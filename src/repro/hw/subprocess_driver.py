@@ -5,8 +5,9 @@ process hosting a TwinDriver) lives outside this interpreter, and the
 control plane reaches it only through the wire protocol — the same
 topology a lab instrument server or a remote chip simulator would have.
 Results are bit-identical to :class:`TwinDriver` for equal construction
-seeds (the server runs the same physics and job code on the same
-backend; raw array bytes round-trip the stream exactly).
+seeds when the parent also runs on the CPU (the server runs the same
+physics and job code on the CPU backend, ``server_env``; raw array
+bytes round-trip the stream exactly).
 
 All protocol behavior (v4 binary frames with the v3 fallback, batch
 frames, write pipelining, the async reader, per-op encode/decode) lives
@@ -37,12 +38,16 @@ def _src_root() -> str:
 
 
 def server_env() -> dict:
-    """Environment for a spawned twin server: import path + matching
+    """Environment for a spawned twin server: import path, matching
     precision regime (or results stop being bit-identical across
-    transports)."""
+    transports), and the CPU backend.  The server models a remote
+    photonic device and never needs an accelerator; on a TPU host the
+    parent process holds the chip, and a child that reached for it
+    would fail or hang."""
     env = dict(os.environ)
     env["PYTHONPATH"] = _src_root() + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_ENABLE_X64"] = "1" if jax.config.jax_enable_x64 else "0"
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

@@ -23,6 +23,7 @@ import numpy as np
 
 from ..core import unitary as un
 from ..core.noise import NoiseModel
+from ..kernels.ptc_block_matmul import accepts as kernel_accepts
 from ..optim.zo import ZOConfig
 from . import jobs
 from .device import (DeviceRealization, sample_device, realized_unitaries,
@@ -106,11 +107,10 @@ def _jitted_probe_ops(k: int, kind: str, model: NoiseModel,
     """Compiled forward/readback graphs keyed on the driver's static
     physics (NoiseModel is a frozen dataclass, hence hashable).
 
-    With ``use_kernels`` (default on TPU backends) the probe forward is
+    With ``use_kernels`` (see :class:`TwinDriver`) the probe forward is
     routed through the Pallas PTC kernel (``kernels.ptc_block_matmul``,
     the production serve-path dataflow: per-block V* → Σ → U on the
-    MXU); elsewhere the XLA einsum of the same physics is faster than
-    interpret-mode Pallas and is used instead.
+    MXU); otherwise through the XLA einsum of the same physics.
     """
     spec = un.mesh_spec(k, kind)
     t = spec.n_rot
@@ -204,11 +204,18 @@ class TwinDriver(PhotonicDriver):
         self._m = int(m) if m is not None else k
         self._n = int(n) if n is not None else k * b
         self._stats = DriverStats()
-        # route the forward paths through the Pallas PTC kernel on TPU
-        # (the production dataflow); XLA einsum elsewhere — interpret-mode
-        # Pallas would undo the fast path on CPU hosts
-        self._use_kernels = (bool(use_kernels) if use_kernels is not None
-                             else jax.default_backend() == "tpu")
+        # route the forward paths through the Pallas PTC kernel (the
+        # production dataflow) on a TPU wherever the kernel lowers at this
+        # k; XLA einsum otherwise — interpret-mode Pallas would undo the
+        # fast path on CPU hosts.  Forcing the kernel at a k it refuses is
+        # an error, never a silent einsum.
+        if use_kernels is None:
+            use_kernels = (jax.default_backend() == "tpu"
+                           and kernel_accepts(k))
+        elif use_kernels and not kernel_accepts(k):
+            raise ValueError(f"the Pallas PTC kernel does not lower at "
+                             f"k={k} (needs a multiple of 128)")
+        self._use_kernels = bool(use_kernels)
         # jitted probe paths, shared across drivers with the same physics
         # (a fleet of N identical chips compiles each graph once, not N×);
         # block-range scoping is compiled in as a static arg, so each
@@ -447,7 +454,7 @@ def make_twin(key: jax.Array, n_blocks: int, k: int, model: NoiseModel,
     (seed-stable with the legacy IC/PM paths); the drift chain derives
     from the same key so one seed pins the whole chip trajectory.
     ``use_kernels`` forces the Pallas forward routing on/off (default:
-    auto — on for TPU backends).
+    auto — on for TPU backends at a k the kernel lowers).
     """
     if dev is None:
         dev = sample_device(key, (n_blocks,), k, model, kind)

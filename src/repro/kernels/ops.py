@@ -1,9 +1,10 @@
-"""Dispatch layer: jit'd public ops that route to the Pallas kernels on TPU
-and to interpret-mode (CPU-executed kernel bodies) elsewhere.
+"""Dispatch layer: jit'd public ops over the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the exact kernel code paths are
-validated on this CPU container; on a real TPU backend the same calls
-compile to Mosaic.
+Whether a kernel runs through the interpreter is decided here and
+nowhere else (:func:`default_interpret`): on a TPU every call compiles
+to Mosaic; on any other backend the same kernel bodies run in interpret
+mode, so the exact kernel code paths are validated on CPU hosts.  The
+kernel modules themselves default to ``interpret=False``.
 """
 
 from __future__ import annotations
@@ -13,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core.unitary import MeshSpec
-from .ptc_block_matmul import ptc_block_matmul as _ptc_block_matmul
+from .ptc_block_matmul import (ptc_block_matmul as _ptc_block_matmul,
+                               row_tile)
 from .mesh_apply import mesh_apply_butterfly as _mesh_apply_butterfly
 from .feedback_matmul import feedback_matmul as _feedback_matmul
 from .sigma_grad import sigma_grad as _sigma_grad
@@ -31,21 +33,9 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _pick_t_tile(t: int, cap: int = 256) -> int:
-    """Largest divisor of t that is ≤ cap (grids need exact tiling)."""
-    best = 1
-    for d in range(1, min(t, cap) + 1):
-        if t % d == 0:
-            best = d
-    return best
-
-
-def ptc_block_matmul(x, u, s, v, *, interpret: bool | None = None):
+def ptc_block_matmul(x, u, s, v):
     """Blocked PTC forward (paper dataflow) via the Pallas kernel."""
-    if interpret is None:
-        interpret = default_interpret()
-    return _ptc_block_matmul(x, u, s, v, t_tile=_pick_t_tile(x.shape[0]),
-                             interpret=interpret)
+    return _ptc_block_matmul(x, u, s, v, interpret=default_interpret())
 
 
 def _coeff_tables(spec: MeshSpec, phases, dtype):
@@ -59,61 +49,47 @@ def _coeff_tables(spec: MeshSpec, phases, dtype):
     return c, s, sign
 
 
-def mesh_apply(spec: MeshSpec, phases, x, d=None, *,
-               interpret: bool | None = None):
+def mesh_apply(spec: MeshSpec, phases, x, d=None):
     """U(Φ, D) @ x via the butterfly kernel.  x: (B, k); phases: (T,)."""
-    if interpret is None:
-        interpret = default_interpret()
     c, s, sign = _coeff_tables(spec, phases, x.dtype)
     if d is None:
         d = jnp.ones((spec.k,), x.dtype)
     return _mesh_apply_butterfly(c, s, sign, d.astype(x.dtype), x,
-                                 b_tile=_pick_t_tile(x.shape[0]),
-                                 interpret=interpret)
+                                 b_tile=row_tile(x.shape[0]),
+                                 interpret=default_interpret())
 
 
-def feedback_matmul(dy, u, s, v, mask, *, interpret: bool | None = None):
+def feedback_matmul(dy, u, s, v, mask):
     """Block-masked feedback pass via the predicated Pallas kernel."""
-    if interpret is None:
-        interpret = default_interpret()
     return _feedback_matmul(dy, u, s, v, mask,
-                            t_tile=_pick_t_tile(dy.shape[0]),
-                            interpret=interpret)
+                            t_tile=row_tile(dy.shape[0]),
+                            interpret=default_interpret())
 
 
-def sigma_grad(dy, x, u, v, *, interpret: bool | None = None):
+def sigma_grad(dy, x, u, v):
     """Fused in-situ Σ-gradient (paper Eq. 5) via the Pallas kernel."""
-    if interpret is None:
-        interpret = default_interpret()
-    return _sigma_grad(dy, x, u, v, t_tile=_pick_t_tile(dy.shape[0]),
-                       interpret=interpret)
+    return _sigma_grad(dy, x, u, v, t_tile=row_tile(dy.shape[0]),
+                       interpret=default_interpret())
 
 
-def paged_gather(table, pages, *, interpret: bool | None = None):
+def paged_gather(table, pages):
     """Paged-KV page assembly (serving gateway) via the Pallas kernel."""
-    if interpret is None:
-        interpret = default_interpret()
-    return _paged_gather(table, pages, interpret=interpret)
+    return _paged_gather(table, pages, interpret=default_interpret())
 
 
-def paged_scatter(idx, new, pages, *, interpret: bool | None = None):
+def paged_scatter(idx, new, pages):
     """Paged-KV token insertion (serving gateway) via the Pallas kernel."""
-    if interpret is None:
-        interpret = default_interpret()
-    return _paged_scatter(idx, new, pages, interpret=interpret)
+    return _paged_scatter(idx, new, pages, interpret=default_interpret())
 
 
-def paged_scatter_rows(idx, rows, pages, *, interpret: bool | None = None):
+def paged_scatter_rows(idx, rows, pages):
     """Multi-token paged-KV insertion (chunked prefill) in one call."""
-    if interpret is None:
-        interpret = default_interpret()
-    return _paged_scatter_rows(idx, rows, pages, interpret=interpret)
+    return _paged_scatter_rows(idx, rows, pages,
+                               interpret=default_interpret())
 
 
-def prefill_attention(lens, q, k, v, *, blk=None, window=None, cap=None,
-                      interpret: bool | None = None):
-    """Chunked paged-prefill attention (serving gateway) via Pallas."""
-    if interpret is None:
-        interpret = default_interpret()
+def prefill_attention(lens, q, k, v, *, blk=None, window=None, cap=None):
+    """Chunked paged-prefill attention (serving gateway) via Pallas.
+    ``blk`` None derives the KV block from the shapes."""
     return _prefill_attention(lens, q, k, v, blk=blk, window=window,
-                              cap=cap, interpret=interpret)
+                              cap=cap, interpret=default_interpret())

@@ -14,14 +14,17 @@ data movements per step:
   DMA idiom.  No compute, pure layout: the copy is exact, so the
   assembled view is bit-identical to the pool contents.
 * **scatter** — write each slot's freshly projected k/v row into its
-  current (page, offset) write position, in place (the pool is aliased
-  into the output, ``input_output_aliases``), one dynamic-slice store
-  per slot.
+  current (page, offset) write position, in place: the pool stays in
+  HBM (``memory_space=pl.ANY``), aliased into the output
+  (``input_output_aliases``), and each row lands by one DMA.  A pool
+  of real size is hundreds of MB, far beyond VMEM, and a one-row store
+  at a dynamic sublane offset does not lower; a DMA that slices only
+  the page and offset axes does both.
 
-``interpret=True`` (the default off-TPU, via ``kernels.ops``) runs the
-exact same kernel bodies on this CPU container; on a TPU backend the
-same calls compile to Mosaic.  Pool/table shapes are static — only the
-table *contents* change per step — so both calls jit cleanly.
+Both kernels take ``interpret`` from ``kernels.ops``, which decides it
+once: interpret mode off-TPU (the kernel bodies run on XLA:CPU),
+Mosaic on a TPU.  Pool/table shapes are static — only the table
+*contents* change per step — so both calls jit cleanly.
 """
 
 from __future__ import annotations
@@ -43,66 +46,78 @@ def _gather_kernel(tbl_ref, pages_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_gather(table: jax.Array, pages: jax.Array, *,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """Assemble per-slot contiguous KV views from a paged pool.
 
     table: (B, J) int32 physical page ids (unallocated entries must
     hold a valid id — 0 by convention; attention masks them by length).
-    pages: (n_pages, page_size, d).  Returns (B, J·page_size, d).
+    pages: (n_pages, page_size, *row).  Returns (B, J·page_size, *row).
+    The table rides flat in SMEM (a 2-D SMEM array pads its rows to
+    128 words).
     """
     b, j = table.shape
-    _, ps, d = pages.shape
+    ps, row = pages.shape[1], pages.shape[2:]
+    zeros = (0,) * len(row)
     out = pl.pallas_call(
         _gather_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, j),
-            in_specs=[pl.BlockSpec((1, ps, d),
-                                   lambda bb, jj, t: (t[bb, jj], 0, 0))],
-            out_specs=pl.BlockSpec((1, 1, ps, d),
-                                   lambda bb, jj, t: (bb, jj, 0, 0))),
-        out_shape=jax.ShapeDtypeStruct((b, j, ps, d), pages.dtype),
+            in_specs=[pl.BlockSpec((1, ps) + row,
+                                   lambda bb, jj, t: (t[bb * j + jj], 0)
+                                   + zeros)],
+            out_specs=pl.BlockSpec((1, 1, ps) + row,
+                                   lambda bb, jj, t: (bb, jj, 0) + zeros)),
+        out_shape=jax.ShapeDtypeStruct((b, j, ps) + row, pages.dtype),
         interpret=interpret,
-    )(table, pages)
-    return out.reshape(b, j * ps, d)
+    )(table.reshape(-1), pages)
+    return out.reshape((b, j * ps) + row)
 
 
-def _scatter_kernel(idx_ref, new_ref, pages_ref, out_ref):
-    del pages_ref                     # aliased into out_ref
-    b = pl.program_id(0)
-    pid = idx_ref[b, 0]
-    off = idx_ref[b, 1]
-    out_ref[pid, pl.ds(off, 1), :] = new_ref[0][None]
+def _scatter_kernel(idx_ref, rows_hbm, pages_hbm, out_hbm, sem):
+    del pages_hbm                     # aliased into out_hbm
+    r = pl.program_id(0)
+    # one row, HBM → HBM, sliced on the untiled leading axes only; it is
+    # awaited before the next grid step, so rows that share a target
+    # (idle slots on the scratch page) resolve last-wins
+    copy = pltpu.make_async_copy(
+        rows_hbm.at[pl.ds(r, 1)],
+        out_hbm.at[idx_ref[2 * r], pl.ds(idx_ref[2 * r + 1], 1)],
+        sem)
+    copy.start()
+    copy.wait()
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_scatter(idx: jax.Array, new: jax.Array, pages: jax.Array, *,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool = False) -> jax.Array:
     """Write one new KV row per slot into its page-table position.
 
     idx: (B, 2) int32 — per slot ``(page_id, offset)`` write position
     (idle slots must point somewhere harmless, e.g. a scratch page).
-    new: (B, d) rows; pages: (n_pages, page_size, d), updated in place
-    via output aliasing.  Returns the updated pool.
+    new: (B, *row); pages: (n_pages, page_size, *row), updated in place
+    via output aliasing.  Returns the updated pool.  On a TPU ``row``
+    needs two or more axes (e.g. ``(Hkv, Dh)``): the DMAs slice the
+    page and offset axes, and a one-row slice of a tiled (minor two)
+    axis does not lower.
     """
-    b = new.shape[0]
-    d = new.shape[-1]
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         _scatter_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b,),
-            in_specs=[pl.BlockSpec((1, d), lambda bb, t: (bb, 0)),
-                      pl.BlockSpec(pages.shape, lambda bb, t: (0, 0, 0))],
-            out_specs=pl.BlockSpec(pages.shape, lambda bb, t: (0, 0, 0))),
+            grid=(new.shape[0],),
+            in_specs=[any_spec, any_spec],
+            out_specs=any_spec,
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
         out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(idx, new, pages)
+    )(idx.reshape(-1), new.astype(pages.dtype), pages)
 
 
 def paged_scatter_rows(idx: jax.Array, rows: jax.Array, pages: jax.Array, *,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """Multi-token scatter: R independent row writes in ONE aliased call.
 
     The chunked-prefill path writes C new KV entries per slot per step;
@@ -115,6 +130,6 @@ def paged_scatter_rows(idx: jax.Array, rows: jax.Array, pages: jax.Array, *,
     and the pool is updated in place through the same
     ``input_output_aliases`` wiring as the one-row path.
 
-    idx: (R, 2) int32; rows: (R, d); pages: (n_pages, page_size, d).
+    idx: (R, 2) int32; rows: (R, *row); pages: (n_pages, page_size, *row).
     """
     return paged_scatter(idx, rows, pages, interpret=interpret)

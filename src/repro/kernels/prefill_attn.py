@@ -23,9 +23,13 @@ can never leak a masked key into the accumulator — that is what makes
 a fully-out-of-window block contribute exactly +0.0 and keeps the
 result bitwise independent of how many padding columns ride along.
 
-Off-TPU this runs in interpret mode (kernel body executed by XLA:CPU),
-like every other kernel in this package; on a TPU backend the same call
-compiles to Mosaic.
+The KV block is derived from the shapes (:func:`kv_block`): the
+largest divisor of S_max whose per-step working set fits a fixed VMEM
+budget, so a long view streams through in pieces instead of landing in
+VMEM whole.  Both contractions accumulate in f32 on the MXU.
+
+``interpret`` comes from ``kernels.ops``: interpret mode off-TPU
+(kernel body executed by XLA:CPU), Mosaic on a TPU.
 """
 
 from __future__ import annotations
@@ -37,9 +41,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["prefill_attention"]
+__all__ = ["prefill_attention", "kv_block"]
 
 NEG_INF = -2.0 ** 30   # finite floor: keeps max/exp arithmetic NaN-free
+
+# Per-step working-set budget for the derived KV block: three quarters
+# of the 16 MiB VMEM that Mosaic scopes to a kernel by default on v5e,
+# leaving the rest for its own temporaries and relayouts.
+VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _step_bytes(blk: int, c: int, h: int, hkv: int, hd: int,
+                itemsize: int) -> int:
+    """VMEM one grid step holds at KV block ``blk``: double-buffered
+    q/out/k/v blocks, the GQA-expanded k/v (and v's f32 copy), f32
+    logits/weights and the online-softmax scratch."""
+    io = 2 * itemsize * (2 * c * h * hd + 2 * blk * hkv * hd)
+    expanded = (2 * itemsize + 4) * blk * h * hd
+    scores = 3 * 4 * h * c * blk
+    scratch = 4 * h * c * (hd + 2)
+    return io + expanded + scores + scratch
+
+
+def kv_block(s: int, c: int, h: int, hkv: int, hd: int,
+             itemsize: int) -> int:
+    """Largest KV block that divides S, is a multiple of 8 (or all of
+    S) and fits :data:`VMEM_BUDGET`; the smallest such block otherwise."""
+    fits = [d for d in range(s, 0, -1)
+            if s % d == 0 and (d % 8 == 0 or d == s)]
+    return next((d for d in fits
+                 if _step_bytes(d, c, h, hkv, hd, itemsize) <= VMEM_BUDGET),
+                fits[-1])
 
 
 def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, out_ref,
@@ -58,7 +90,8 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, out_ref,
     kb = jnp.repeat(k_ref[0], rep, axis=1)         # (blk, H, Dh) GQA expand
     vb = jnp.repeat(v_ref[0], rep, axis=1)
     c = q.shape[0]
-    logits = jnp.einsum("qhd,khd->hqk", q, kb).astype(jnp.float32) * scale
+    logits = jnp.einsum("qhd,khd->hqk", q, kb,
+                        preferred_element_type=jnp.float32) * scale
     if cap is not None:
         logits = cap * jnp.tanh(logits / cap)
     ln = lens_ref[b]
@@ -74,7 +107,8 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, out_ref,
     denom_ref[...] = denom_ref[...] * alpha + p.sum(-1)
     acc_ref[...] = (acc_ref[...] * alpha[..., None]
                     + jnp.einsum("hqk,khd->hqd", p,
-                                 vb.astype(jnp.float32)))
+                                 vb.astype(jnp.float32),
+                                 preferred_element_type=jnp.float32))
     m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -87,7 +121,7 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, out_ref,
                    static_argnames=("blk", "window", "cap", "interpret"))
 def prefill_attention(lens, q, k, v, *, blk: int | None = None,
                       window: int | None = None, cap: float | None = None,
-                      interpret: bool = True):
+                      interpret: bool = False):
     """Chunked-causal prefill attention over per-slot KV views.
 
     lens: (B,) int32 — tokens already in each slot's cache (the chunk's
@@ -95,13 +129,14 @@ def prefill_attention(lens, q, k, v, *, blk: int | None = None,
     q: (B, C, H, Dh) rope'd queries for the C-token chunk.
     k, v: (B, S_max, Hkv, Dh) page-assembled views WITH the chunk's own
     rows already spliced in at ``lens[b]..lens[b]+C-1``.
-    blk: KV block size (must divide S_max); None = one block, the whole
-    view.  cap: attention logit soft-cap (gemma2); window: sliding
-    window.  Returns (B, C, H, Dh) attended values in q's dtype.
+    blk: KV block size (must divide S_max); None derives it from the
+    shapes (:func:`kv_block`).  cap: attention logit soft-cap (gemma2);
+    window: sliding window.  Returns (B, C, H, Dh) attended values in q's dtype.
     """
     b, c, h, hd = q.shape
     s, hkv = k.shape[1], k.shape[2]
-    blk = s if blk is None else int(blk)
+    if blk is None:
+        blk = kv_block(s, c, h, hkv, hd, k.dtype.itemsize)
     if s % blk:
         raise ValueError(f"kv view length {s} not divisible by block {blk}")
     kern = functools.partial(_prefill_kernel, blk=blk, rep=h // hkv,

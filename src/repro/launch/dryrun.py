@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"   # virtual devices; never the chip
 
 """Multi-pod dry-run: prove the distribution config is coherent.
 

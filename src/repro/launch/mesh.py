@@ -11,6 +11,7 @@ cross it.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "dp_axes", "MODEL_AXIS"]
 
@@ -20,7 +21,11 @@ MODEL_AXIS = "model"
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the partitioner places what sharding constraints leave
+    # open (jax 0.9 defaults to Explicit, which with_sharding_constraint
+    # refuses)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..models.lm import (ArchConfig, period_plan, _sublayer_fwd, _apply_norm,
                          embed, softcap, cross_entropy)
 
@@ -138,9 +137,9 @@ def build_pp_loss(cfg: ArchConfig, n_stages: int, n_micro: int):
             P(), P(), P(PIPE_AXIS))
         # manual ONLY over the pipe axis — data/model stay under the
         # partitioner (the inner stage compute keeps its DP/TP sharding)
-        fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=P(), check_vma=False,
-                       axis_names=frozenset({PIPE_AXIS}))
+        fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=P(), check_vma=False,
+                           axis_names=frozenset({PIPE_AXIS}))
         return fn({**other, "stack_local": stack},
                   batch["tokens"], batch["labels"],
                   jnp.arange(n_stages, dtype=jnp.int32))

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..data import lm_batch
 from ..models.lm import (ArchConfig, init_model, init_decode_cache,
                          build_serve_step)
@@ -300,6 +301,7 @@ def main(argv=None):
     add_gateway_args(ap)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.gateway:
         rep = run(args)
         c = rep["config"]

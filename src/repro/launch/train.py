@@ -29,6 +29,7 @@ import numpy as np
 from ..configs import get_config, smoke_config
 from ..core.sparsity import SparsityConfig, smd_keep_iteration
 from ..checkpoint import CheckpointManager
+from ..compile_cache import enable_compile_cache
 from ..data import lm_batch
 from ..optim.optimizers import AdamWConfig
 from ..optim.schedules import linear_warmup_cosine
@@ -60,6 +61,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = parse_arch(args.arch)
     scfg = SparsityConfig(alpha_w=args.alpha_w, alpha_c=args.alpha_c,
                           alpha_d=args.alpha_d)
@@ -79,7 +81,8 @@ def main(argv=None):
             step0 = int(meta["step"]) + 1
             print(f"resumed from step {meta['step']}")
 
-    update = jax.jit(build_update_step(cfg, ocfg, scfg, sched))
+    update = jax.jit(build_update_step(cfg, ocfg, scfg, sched),
+                     donate_argnums=(0, 1))
 
     losses = []
     t_train0 = time.time()

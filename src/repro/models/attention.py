@@ -257,7 +257,7 @@ def decode_attention_paged(p: Params, cfg: AttnCfg, lin: PTCLinearCfg, x,
 
 def decode_attention_paged_chunked(p: Params, cfg: AttnCfg,
                                    lin: PTCLinearCfg, x, k_view, v_view,
-                                   lens, kv_block: int | None = None):
+                                   lens):
     """C-token chunked prefill against page-assembled per-slot views.
 
     x: (B, C, d) — each slot's next C tokens (padding columns past the
@@ -266,8 +266,8 @@ def decode_attention_paged_chunked(p: Params, cfg: AttnCfg,
     (B,) int32 cache lengths, so chunk column c sits at absolute
     position ``lens[b] + c``.  Attention runs through the Pallas
     online-softmax kernel (``kernels.prefill_attention``) over the view
-    with the chunk's own K/V rows spliced in, ``kv_block`` keys at a
-    time.
+    with the chunk's own K/V rows spliced in, one shape-derived KV block
+    at a time.
 
     The splice deliberately avoids ``dynamic_update_slice`` — its start
     index CLAMPS, so a slot near the end of its reservation would slide
@@ -292,8 +292,8 @@ def decode_attention_paged_chunked(p: Params, cfg: AttnCfg,
         return jnp.where(in_chunk[:, :, None, None], g, view)
 
     o = prefill_attention(lens, q, splice(k_view, k_new),
-                          splice(v_view, v_new), blk=kv_block,
-                          window=cfg.window, cap=cfg.attn_softcap)
+                          splice(v_view, v_new), window=cfg.window,
+                          cap=cfg.attn_softcap)
     o = o.reshape(b, c, cfg.n_heads * cfg.head_dim)
     out = apply_ptc_linear(p["wo"], o, lin, d_out=cfg.d_model, name="wo")
     return out, k_new, v_new

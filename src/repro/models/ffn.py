@@ -19,7 +19,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 from .layers import (PTCLinearCfg, init_ptc_linear, apply_ptc_linear,
                      maybe_constraint)
 
@@ -168,7 +167,7 @@ def _moe_a2a(p: Params, cfg: MoECfg, lin: PTCLinearCfg, x: jax.Array,
     # the 2D EP layout (tokens dp-only would replicate routing + expert
     # work 16× across the model axis)
     tok_axes = dp + ("model",)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), espec, P(tok_axes, None, None)),
         out_specs=(P(tok_axes, None, None), P()),

@@ -18,6 +18,7 @@ computes exactly the sampled estimator the photonic chip would.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -268,8 +269,7 @@ def inject_masks(params: Params, key: jax.Array, scfg: SparsityConfig,
                 if scfg.alpha_c < 1.0:
                     kc = jax.random.fold_in(k, 1)
                     if lead:
-                        kcs = jax.random.split(kc, int(jnp.prod(
-                            jnp.asarray(lead))))
+                        kcs = jax.random.split(kc, math.prod(lead))
                         col = jax.vmap(lambda kk: column_mask(
                             kk, n_tokens, scfg))(kcs)
                         out["col"] = col.reshape(lead + (n_tokens,))
@@ -641,7 +641,7 @@ def build_gateway_step(cfg: ArchConfig):
     return gateway_step
 
 
-def build_gateway_prefill_step(cfg: ArchConfig, kv_block: int | None = None):
+def build_gateway_prefill_step(cfg: ArchConfig):
     """Returns prefill_step(params, views, batch) → (logits, new_kv):
     the chunked-prefill gateway step — every slot advances up to C
     tokens per call instead of one.
@@ -660,8 +660,8 @@ def build_gateway_prefill_step(cfg: ArchConfig, kv_block: int | None = None):
     PTC scope names are IDENTICAL to :func:`build_gateway_step`
     (``p{period}.s{sub}.attn.wq`` …): a hardware deployment recorded
     off the solo serve path routes the wide (B·C-column) prefill frames
-    onto the same tenants untouched.  ``kv_block`` sets the Pallas
-    kernel's KV block size (None = whole view per block).
+    onto the same tenants untouched.  The Pallas kernel derives its KV
+    block from the shapes.
 
     Attention-only: ssm/hybrid recurrences are inherently sequential in
     tokens, and vlm/encdec/MoE are not paged at all — those archs keep
@@ -695,7 +695,7 @@ def build_gateway_prefill_step(cfg: ArchConfig, kv_block: int | None = None):
                 with ptc_scope(f"s{i}.attn"):
                     h, k_new, v_new = decode_attention_paged_chunked(
                         p["attn"], cfg.attn_cfg(sub.window), cfg.ptc,
-                        h, c["k"], c["v"], lens, kv_block=kv_block)
+                        h, c["k"], c["v"], lens)
                 new[f"pos{i}"] = {"k": k_new, "v": v_new}
                 if cfg.post_norm:
                     h = _apply_norm(cfg, p["pn1"], h)

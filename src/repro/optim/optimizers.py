@@ -66,7 +66,10 @@ def init_opt_state(params: PyTree, trainable: PyTree | None = None
         return jnp.zeros(a.shape if tr else (), jnp.float32)
 
     def m(a, tr):
-        return a.astype(jnp.float32) if tr else jnp.zeros((), jnp.float32)
+        # a copy even where ``a`` is already f32: the master weights are
+        # their own buffers, so a step may donate params and state both
+        return (jnp.array(a, jnp.float32, copy=True) if tr
+                else jnp.zeros((), jnp.float32))
 
     return OptState(step=jnp.zeros((), jnp.int32),
                     mu=jax.tree.map(z, params, trainable),

@@ -36,6 +36,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from ..compile_cache import enable_compile_cache
 from ..core.noise import DEFAULT_NOISE
 from ..hw import DriftConfig
 from .monitor import MonitorConfig
@@ -280,6 +281,7 @@ def main(argv=None) -> int:
                     help="autopilot: budget window in ticks")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     autopilot = None
     if args.autopilot:
         from .autopilot import AutopilotConfig

@@ -55,7 +55,6 @@ class GatewayConfig:
     # tokens to stride C at width C (row-position invariance at fixed
     # shape) — the property tests' comparison lever.  None = C.
     prefill_stride: int | None = None
-    kv_block: int | None = None  # prefill kernel KV block (None = whole view)
 
 
 def build_gateway_hw_plane(key, cfg: ArchConfig, params, runtime_cfg,
@@ -96,14 +95,13 @@ class ServingGateway:
         self.stride = (self.chunk if gcfg.prefill_stride is None
                        else max(1, min(int(gcfg.prefill_stride), self.chunk)))
         if self.chunk > 1:
-            self._step_fn = build_gateway_prefill_step(
-                cfg, kv_block=gcfg.kv_block)
+            self._step_fn = build_gateway_prefill_step(cfg)
         else:
             self._step_fn = build_gateway_step(cfg)
         if hw_plane is None:
             self._step_fn = jax.jit(self._step_fn)
 
-        # tensor pools: one (P·(n_pages+1), page_size, Hkv·Dh) pair per
+        # tensor pools: one (P·(n_pages+1), page_size, Hkv, Dh) pair per
         # attention sub-layer position — all periods share the slot page
         # table (token t lives at the same page/offset in every layer),
         # each period's pages offset by its stripe.  The +1 page per
@@ -122,7 +120,7 @@ class ServingGateway:
                 acfg = cfg.attn_cfg(sub.window)
                 hk, hd = acfg.n_kv_heads, acfg.head_dim
                 self._kv_dims[name] = (hk, hd)
-                shape = (self.n_periods * self._stripe, ps, hk * hd)
+                shape = (self.n_periods * self._stripe, ps, hk, hd)
                 self._pools[name] = {"k": jnp.zeros(shape, kv_dtype),
                                      "v": jnp.zeros(shape, kv_dtype)}
             else:
@@ -182,7 +180,7 @@ class ServingGateway:
             hk, hd = self._kv_dims[name]
             rows = new_kv[name]     # {"k","v"}: (P, B, 1, Hkv, Dh)
             for kk in ("k", "v"):
-                flat = rows[kk].reshape(self.n_periods * b, hk * hd)
+                flat = rows[kk].reshape(self.n_periods * b, hk, hd)
                 pools[kk] = paged_scatter(
                     full_idx, flat.astype(pools[kk].dtype), pools[kk])
         for name in self._ssm:
@@ -213,7 +211,7 @@ class ServingGateway:
             hk, hd = self._kv_dims[name]
             rows = new_kv[name]     # {"k","v"}: (P, B, C, Hkv, Dh)
             for kk in ("k", "v"):
-                flat = rows[kk].reshape(self.n_periods * b * c, hk * hd)
+                flat = rows[kk].reshape(self.n_periods * b * c, hk, hd)
                 pools[kk] = paged_scatter_rows(
                     full_idx, flat.astype(pools[kk].dtype), pools[kk])
 

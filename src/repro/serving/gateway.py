@@ -19,6 +19,7 @@ import dataclasses
 
 import jax
 
+from ..compile_cache import enable_compile_cache
 from ..models.lm import ArchConfig, init_model
 from .engine import GatewayConfig, ServingGateway, build_gateway_hw_plane
 from .kv_pages import PageConfig
@@ -102,8 +103,7 @@ def run(args) -> dict:
         pages=PageConfig(page_size=args.page_size, n_pages=args.pages,
                          max_pages_per_slot=args.max_pages_per_slot),
         prefill_chunk=getattr(args, "prefill_chunk", 1) or 1,
-        prefill_stride=getattr(args, "prefill_stride", None),
-        kv_block=getattr(args, "kv_block", None))
+        prefill_stride=getattr(args, "prefill_stride", None))
     plane = None
     if hw_mode is not None:
         kf = jax.random.split(jax.random.PRNGKey(args.seed + 17))[1]
@@ -147,6 +147,7 @@ def main(argv=None):
     add_autopilot_args(ap)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     rep = run(args)
     c = rep["config"]
     lat, wait = rep["latency_steps"], rep["admission_wait_steps"]

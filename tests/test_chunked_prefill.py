@@ -117,6 +117,25 @@ def test_prefill_kernel_fully_masked_block_is_exact_zero():
     np.testing.assert_array_equal(np.asarray(base), np.asarray(poked))
 
 
+@pytest.mark.parametrize("s,c,h,hkv,hd,itemsize,want", [
+    (1024, 32, 32, 8, 128, 2, 128),   # qwen3-4b chunk over a 1024 view
+    (8192, 32, 32, 8, 128, 2, 128),
+    (1000, 32, 32, 8, 128, 2, 200),   # largest fitting divisor
+    (24, 5, 4, 2, 8, 4, 24),          # small views stay one block
+])
+def test_prefill_kv_block_is_derived_from_shapes(s, c, h, hkv, hd, itemsize,
+                                                 want):
+    """The derived block divides the view, is a multiple of 8 or the
+    whole view, and keeps one grid step inside the VMEM budget."""
+    from repro.kernels import prefill_attn
+
+    blk = prefill_attn.kv_block(s, c, h, hkv, hd, itemsize)
+    assert blk == want
+    assert s % blk == 0 and (blk % 8 == 0 or blk == s)
+    assert (prefill_attn._step_bytes(blk, c, h, hkv, hd, itemsize)
+            <= prefill_attn.VMEM_BUDGET)
+
+
 def test_prefill_kernel_rejects_indivisible_block():
     from repro.kernels.ops import prefill_attention
 
@@ -162,8 +181,7 @@ def test_write_span_refuses_past_reservation():
 # ---------------------------------------------------------------------------
 
 
-def _run_single(prompt_len, max_new, chunk, stride, page_size, kv_block,
-                seed=9):
+def _run_single(prompt_len, max_new, chunk, stride, page_size, seed=9):
     rng = np.random.default_rng(seed)
     reqs = [Request(rid=0, prompt=rng.integers(
         0, ARCH.vocab, size=(prompt_len,)).astype(np.int32),
@@ -173,7 +191,7 @@ def _run_single(prompt_len, max_new, chunk, stride, page_size, kv_block,
         pages=PageConfig(page_size=page_size, n_pages=24,
                          max_pages_per_slot=-(-(prompt_len + max_new)
                                               // page_size)),
-        prefill_chunk=chunk, prefill_stride=stride, kv_block=kv_block)
+        prefill_chunk=chunk, prefill_stride=stride)
     gw = ServingGateway(ARCH, PARAMS, gcfg)
     rep = gw.run(reqs)
     stripe = gcfg.pages.n_pages + 1
@@ -186,23 +204,19 @@ def _run_single(prompt_len, max_new, chunk, stride, page_size, kv_block,
 
 @settings(max_examples=6, deadline=None)
 @given(prompt_len=st.integers(1, 22), chunk=st.sampled_from([2, 3, 5, 8]),
-       stride=st.integers(1, 8), page_size=st.sampled_from([2, 4, 8]),
-       kv_block=st.sampled_from([None, 8]))
+       stride=st.integers(1, 8), page_size=st.sampled_from([2, 4, 8]))
 def test_chunked_prefill_bitwise_kv_and_token_identity(
-        prompt_len, chunk, stride, page_size, kv_block):
+        prompt_len, chunk, stride, page_size):
     """stride s ≤ C at padded width C is bit-identical in pool contents
     and tokens to stride C at width C; both emit the legacy one-token
-    path's tokens.  kv_block=8 exercises the multi-block online-softmax
-    accumulation end-to-end (S_max is a multiple of 8 by geometry)."""
+    path's tokens.  (The multi-block online-softmax accumulation is
+    swept at the kernel level above; here the block is derived.)"""
     stride = min(stride, chunk)
     max_new = 3
-    if kv_block is not None:
-        page_size = 8       # keep S_max divisible by the kernel block
-    tok_c, pool_c = _run_single(prompt_len, max_new, chunk, None,
-                                page_size, kv_block)
+    tok_c, pool_c = _run_single(prompt_len, max_new, chunk, None, page_size)
     tok_s, pool_s = _run_single(prompt_len, max_new, chunk, stride,
-                                page_size, kv_block)
-    tok_1, _ = _run_single(prompt_len, max_new, 1, None, page_size, None)
+                                page_size)
+    tok_1, _ = _run_single(prompt_len, max_new, 1, None, page_size)
     assert tok_c == tok_s == tok_1
     assert pool_c.keys() == pool_s.keys() and len(pool_c) > 0
     for name in pool_c:

@@ -344,6 +344,37 @@ def test_ptc_call_accounting(transport):
         driver.close()
 
 
+def test_twin_kernel_route_matches_einsum_at_k128():
+    """The Pallas PTC route (interpret mode off-TPU) gives the einsum
+    route's probe and layer outputs at k=128, over token counts that are
+    not multiples of 8."""
+    k, p, q = 128, 2, 3
+    key = jax.random.PRNGKey(7)
+    kern, plain = (make_twin(key, p * q, k, MODEL, m=p * k, n=q * k,
+                             use_kernels=flag) for flag in (True, False))
+    for drv in (kern, plain):
+        drv.write_sigma(jnp.linspace(0.5, 1.5, p * q * k))
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((5, q * k)), jnp.float32)
+    y = np.asarray(kern.forward_layer(x))
+    assert y.shape == (5, p * k)
+    np.testing.assert_allclose(y, np.asarray(plain.forward_layer(x)),
+                               rtol=1e-4, atol=1e-4)
+    xp = jnp.asarray(rng.standard_normal((11, k)), jnp.float32)
+    np.testing.assert_allclose(np.asarray(kern.forward(xp)),
+                               np.asarray(plain.forward(xp)),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("k", [4, 9, 64])
+def test_twin_refuses_kernel_route_where_it_cannot_lower(k):
+    """Forcing the kernel at a k Mosaic refuses is an error; the default
+    route never picks it there."""
+    with pytest.raises(ValueError, match="multiple of 128"):
+        make_twin(KEY, 2, k, MODEL, use_kernels=True)
+    assert not make_twin(KEY, 2, k, MODEL)._use_kernels
+
+
 def test_unsafe_twin_raises_without_twin_backing():
     """A driver not backed by an inspectable twin refuses the hatch."""
 

@@ -13,7 +13,8 @@ SCRIPT = r"""
 import jax, jax.numpy as jnp
 from repro.models.ffn import MoECfg, init_moe, moe
 from repro.models.layers import PTCLinearCfg
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ptc = PTCLinearCfg(k=8, mode="fused", base_dtype=jnp.float32)
 kw = dict(d_model=32, d_ff=64, n_experts=8, top_k=2, capacity_factor=8.0)
 cfg_p = MoECfg(dispatch="pjit", **kw)

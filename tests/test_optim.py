@@ -103,9 +103,8 @@ def test_compression_error_feedback():
 def test_psum_compressed_single_device():
     """shard_map psum path on a 1-device mesh (semantics check)."""
     from repro.optim.compression import psum_compressed
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    from jax.sharding import AxisType, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     g = {"w": jnp.asarray([1.0, -2.0, 3.0])}
     st = init_compression(g)
 
@@ -113,7 +112,7 @@ def test_psum_compressed_single_device():
         out, st2 = psum_compressed(g, CompressionState(error=e), "data")
         return out, st2.error
 
-    fm = shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+    fm = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
     out, err = fm(g, st.error)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                atol=3 / 127 + 1e-4)

@@ -13,7 +13,8 @@ from repro.configs import smoke_config
 from repro.models.lm import init_model, forward, cross_entropy
 from repro.launch.pipeline import build_pp_loss
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 cfg = dataclasses.replace(smoke_config("olmo-1b"), n_layers=4, remat=True)
 params = init_model(jax.random.PRNGKey(0), cfg)
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
@@ -26,20 +27,13 @@ pp = build_pp_loss(cfg, n_stages=2, n_micro=2)
 with mesh:
     got = float(jax.jit(lambda p, b: pp(p, b, mesh))(params, batch))
     assert abs(ref - got) < 1e-5, (ref, got)
-    if hasattr(jax, "shard_map"):
-        # grad-of-shard_map transpose is broken on jax 0.4.x (scalar
-        # residuals that vary over manual axes fail the spec check both
-        # with and without check_rep); forward equivalence above still
-        # runs everywhere via repro.compat's full-manual fallback.
-        g = jax.jit(jax.grad(lambda p, b: pp(p, b, mesh)))(params, batch)
-        gref = jax.grad(lambda p: cross_entropy(
-            forward(p, cfg, {"tokens": batch["tokens"]})[0],
-            batch["labels"]))(params)
-        a = g["pos0"]["attn"]["wq"]["s"]
-        b = gref["pos0"]["attn"]["wq"]["s"]
-        assert float(jnp.abs(a - b).max()) < 1e-5
-    else:
-        print("PP_GRAD_SKIPPED(jax<0.5)")
+    g = jax.jit(jax.grad(lambda p, b: pp(p, b, mesh)))(params, batch)
+    gref = jax.grad(lambda p: cross_entropy(
+        forward(p, cfg, {"tokens": batch["tokens"]})[0],
+        batch["labels"]))(params)
+    a = g["pos0"]["attn"]["wq"]["s"]
+    b = gref["pos0"]["attn"]["wq"]["s"]
+    assert float(jnp.abs(a - b).max()) < 1e-5
 print("PP_OK")
 """
 

@@ -96,7 +96,8 @@ def test_batch_and_cache_shardings_build():
     mesh (structure check only)."""
     from repro.launch.sharding import batch_shardings, cache_shardings
     from repro.models.lm import init_decode_cache
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = smoke_config("qwen3-4b")
     batch = {"tokens": jax.ShapeDtypeStruct((4, 8), jnp.int32),
              "cache_len": jax.ShapeDtypeStruct((), jnp.int32)}
