@@ -88,8 +88,8 @@ def paged_scatter_rows(idx, rows, pages):
                                interpret=default_interpret())
 
 
-def prefill_attention(lens, q, k, v, *, blk=None, window=None, cap=None):
-    """Chunked paged-prefill attention (serving gateway) via Pallas.
-    ``blk`` None derives the KV block from the shapes."""
-    return _prefill_attention(lens, q, k, v, blk=blk, window=window,
-                              cap=cap, interpret=default_interpret())
+def prefill_attention(lens, q, k, v, *, window=None, cap=None):
+    """Chunked paged-prefill attention (serving gateway) via Pallas; the
+    KV block is derived from the shapes (``prefill_attn.kv_block``)."""
+    return _prefill_attention(lens, q, k, v, window=window, cap=cap,
+                              interpret=default_interpret())

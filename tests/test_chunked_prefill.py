@@ -26,6 +26,7 @@ Guarantee structure:
 
 import argparse
 import dataclasses
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -83,7 +84,7 @@ def _reference(lens, q, k, v, window=None, cap=None):
 @pytest.mark.parametrize("window,cap", [(None, None), (6, None),
                                         (None, 3.0), (5, 2.0)])
 def test_prefill_kernel_matches_dense_reference(blk, window, cap):
-    from repro.kernels.ops import prefill_attention
+    from repro.kernels.prefill_attn import prefill_attention
 
     rng = np.random.default_rng(0)
     b, c, h, hkv, hd, s = 3, 5, 4, 2, 8, 24
@@ -91,17 +92,19 @@ def test_prefill_kernel_matches_dense_reference(blk, window, cap):
     q = jnp.asarray(rng.normal(size=(b, c, h, hd)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, s, hkv, hd)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, s, hkv, hd)), jnp.float32)
-    got = prefill_attention(lens, q, k, v, blk=blk, window=window, cap=cap)
+    got = prefill_attention(lens, q, k, v, blk=blk, window=window, cap=cap,
+                            interpret=True)
     want = _reference(lens, q, k, v, window=window, cap=cap)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
-    again = prefill_attention(lens, q, k, v, blk=blk, window=window, cap=cap)
+    again = prefill_attention(lens, q, k, v, blk=blk, window=window, cap=cap,
+                              interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(again))
 
 
 def test_prefill_kernel_fully_masked_block_is_exact_zero():
     """A KV block entirely outside the causal window must contribute
     exactly nothing — the masked-exp discipline, not just allclose."""
-    from repro.kernels.ops import prefill_attention
+    from repro.kernels.prefill_attn import prefill_attention
 
     rng = np.random.default_rng(1)
     b, c, h, hkv, hd, s = 1, 2, 2, 1, 4, 16
@@ -109,11 +112,12 @@ def test_prefill_kernel_fully_masked_block_is_exact_zero():
     q = jnp.asarray(rng.normal(size=(b, c, h, hd)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, s, hkv, hd)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, s, hkv, hd)), jnp.float32)
-    base = prefill_attention(lens, q, k, v, blk=4, window=3)
+    base = prefill_attention(lens, q, k, v, blk=4, window=3, interpret=True)
     # rewrite the keys/values the window can never see; output unchanged
     k2 = k.at[:, :8].set(999.0)
     v2 = v.at[:, :8].set(-999.0)
-    poked = prefill_attention(lens, q, k2, v2, blk=4, window=3)
+    poked = prefill_attention(lens, q, k2, v2, blk=4, window=3,
+                              interpret=True)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(poked))
 
 
@@ -137,13 +141,14 @@ def test_prefill_kv_block_is_derived_from_shapes(s, c, h, hkv, hd, itemsize,
 
 
 def test_prefill_kernel_rejects_indivisible_block():
-    from repro.kernels.ops import prefill_attention
+    from repro.kernels.prefill_attn import prefill_attention
 
     with pytest.raises(ValueError, match="not divisible"):
         prefill_attention(jnp.zeros((1,), jnp.int32),
                           jnp.zeros((1, 2, 2, 4), jnp.float32),
                           jnp.zeros((1, 10, 1, 4), jnp.float32),
-                          jnp.zeros((1, 10, 1, 4), jnp.float32), blk=4)
+                          jnp.zeros((1, 10, 1, 4), jnp.float32), blk=4,
+                          interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -186,11 +191,13 @@ def _run_single(prompt_len, max_new, chunk, stride, page_size, seed=9):
     reqs = [Request(rid=0, prompt=rng.integers(
         0, ARCH.vocab, size=(prompt_len,)).astype(np.int32),
         max_new=max_new, arrival=0)]
+    # the view S_max is a multiple of 8 and at least 16, so it has a KV
+    # block of 8 smaller than itself for the kernel to stream through
+    s_max = -(-max(16, prompt_len + max_new) // 8) * 8
     gcfg = GatewayConfig(
         slots=2,
         pages=PageConfig(page_size=page_size, n_pages=24,
-                         max_pages_per_slot=-(-(prompt_len + max_new)
-                                              // page_size)),
+                         max_pages_per_slot=s_max // page_size),
         prefill_chunk=chunk, prefill_stride=stride)
     gw = ServingGateway(ARCH, PARAMS, gcfg)
     rep = gw.run(reqs)
@@ -204,19 +211,45 @@ def _run_single(prompt_len, max_new, chunk, stride, page_size, seed=9):
 
 @settings(max_examples=6, deadline=None)
 @given(prompt_len=st.integers(1, 22), chunk=st.sampled_from([2, 3, 5, 8]),
-       stride=st.integers(1, 8), page_size=st.sampled_from([2, 4, 8]))
+       stride=st.integers(1, 8), page_size=st.sampled_from([2, 4, 8]),
+       kv_blk=st.sampled_from([None, 8]))
 def test_chunked_prefill_bitwise_kv_and_token_identity(
-        prompt_len, chunk, stride, page_size):
+        prompt_len, chunk, stride, page_size, kv_blk):
     """stride s ≤ C at padded width C is bit-identical in pool contents
     and tokens to stride C at width C; both emit the legacy one-token
-    path's tokens.  (The multi-block online-softmax accumulation is
-    swept at the kernel level above; here the block is derived.)"""
+    path's tokens.  ``kv_blk`` 8 shrinks the kernel's VMEM budget to one
+    block of 8 rows, so the derived block is smaller than the view and
+    the online softmax accumulates over several blocks; None keeps the
+    default budget, under which the whole view is one block."""
+    from repro.kernels import prefill_attn
+
     stride = min(stride, chunk)
     max_new = 3
-    tok_c, pool_c = _run_single(prompt_len, max_new, chunk, None, page_size)
-    tok_s, pool_s = _run_single(prompt_len, max_new, chunk, stride,
-                                page_size)
+    budget = prefill_attn.VMEM_BUDGET
+    if kv_blk is not None:
+        budget = prefill_attn._step_bytes(kv_blk, chunk, ARCH.n_heads,
+                                          ARCH.n_kv_heads, ARCH.head_dim, 4)
+    derived, kv_block = [], prefill_attn.kv_block
+
+    def spy(s, *dims):
+        derived.append((s, kv_block(s, *dims)))
+        return derived[-1][1]
+
+    # traces cached under another budget would skip the derivation
+    jax.clear_caches()
+    with mock.patch.object(prefill_attn, "VMEM_BUDGET", budget), \
+            mock.patch.object(prefill_attn, "kv_block", spy):
+        tok_c, pool_c = _run_single(prompt_len, max_new, chunk, None,
+                                    page_size)
+        tok_s, pool_s = _run_single(prompt_len, max_new, chunk, stride,
+                                    page_size)
+    jax.clear_caches()
     tok_1, _ = _run_single(prompt_len, max_new, 1, None, page_size)
+    assert derived, "the prefill kernel was not traced"
+    if kv_blk is None:
+        assert all(blk == s for s, blk in derived), derived
+    else:
+        assert all(blk % 8 == 0 and blk < s for s, blk in derived), derived
     assert tok_c == tok_s == tok_1
     assert pool_c.keys() == pool_s.keys() and len(pool_c) > 0
     for name in pool_c:
