@@ -72,10 +72,14 @@ def ptc_execution(hook: Callable):
 
 @contextlib.contextmanager
 def ptc_scope(name: str):
-    """Push a qualifier onto the PTC layer-name scope stack."""
+    """Push a qualifier onto the PTC layer-name scope stack, and onto
+    JAX's name stack, so every op traced inside carries it in its HLO
+    ``op_name`` metadata (and a profile can attribute device time to
+    ``s0.attn``, ``s0.mlp`` ...)."""
     _PTC_SCOPE.append(name)
     try:
-        yield
+        with jax.named_scope(name):
+            yield
     finally:
         _PTC_SCOPE.pop()
 
@@ -161,7 +165,17 @@ def apply_ptc_linear(p: Params, x: jax.Array, cfg: PTCLinearCfg,
 
     ``name`` identifies the layer to an installed :func:`ptc_execution`
     hook (hardware-in-the-loop serving); unnamed calls never leave the
-    digital path."""
+    digital path.  A named call's ops also carry ``name`` as a scope of
+    their HLO ``op_name``."""
+    if name is None:
+        return _apply_ptc_linear(p, x, cfg, masks, d_out, name)
+    with jax.named_scope(name):
+        return _apply_ptc_linear(p, x, cfg, masks, d_out, name)
+
+
+def _apply_ptc_linear(p: Params, x: jax.Array, cfg: PTCLinearCfg,
+                      masks: SubspaceMasks | None, d_out: int | None,
+                      name: str | None) -> jax.Array:
     y = _hook_dispatch(p, x, cfg, d_out, name)
     if y is not None:
         if "b" in p:

@@ -135,6 +135,10 @@ class ServingGateway:
         self.step_count = 0
         self.busy_steps = 0
         self.slot_steps = 0          # Σ active slots over busy steps
+        # Σ over busy steps of the KV positions each active slot's
+        # attention covers (its length before the step plus the tokens
+        # it takes in), against busy_steps · slots · S_max gathered
+        self.kv_live_positions = 0
         self.tokens_out = 0
 
     # -- paged-pool plumbing -------------------------------------------------
@@ -235,7 +239,14 @@ class ServingGateway:
         ``prefill_chunk`` C > 1 a prefilling slot ingests up to
         min(prefill_stride, remaining) prompt tokens per step while
         decode slots produce one token each (n_valid == 1), all through
-        one (B, C)-wide forward."""
+        one (B, C)-wide forward.
+
+        Each busy step runs inside a ``jax.profiler`` step span
+        ``gateway.step`` holding one span per phase, in order:
+        ``gateway.admit``, ``stage``, ``gather``, ``forward``,
+        ``scatter``, ``fetch`` (the blocking device-to-host argmax) and
+        ``emit``.  They cost well under a microsecond each while no
+        profile is recorded."""
         sched = Scheduler(self.pool)
         todo = sorted(requests, key=lambda r: (r.arrival, r.rid))
         next_arrival = 0
@@ -250,20 +261,14 @@ class ServingGateway:
         last_tok = np.zeros((b,), np.int32)  # last emitted token per slot
         arange_b = np.arange(b)
         arange_c = np.arange(chunk)
-        t0 = time.time()
+        t0 = time.perf_counter()
+        span = jax.profiler.TraceAnnotation
         with hook_ctx:
             while self.step_count < self.gcfg.max_steps:
                 step = self.step_count
-                while (next_arrival < len(todo)
-                       and todo[next_arrival].arrival <= step):
-                    sched.submit(todo[next_arrival], step)
-                    next_arrival += 1
-                for slot, req in sched.admit(step):
-                    slot_pos[slot] = 0
-                    plen[slot] = req.prompt_len
-                    prompt_buf[slot, :req.prompt_len] = req.prompt
-                    self._reset_slot(slot)
-                if sched.idle:
+                # no slot running and no arrival due: an idle step
+                if sched.idle and not (next_arrival < len(todo)
+                                       and todo[next_arrival].arrival <= step):
                     if next_arrival >= len(todo):
                         break                      # drained
                     # open-loop gap: virtual time still passes (drift
@@ -275,64 +280,96 @@ class ServingGateway:
                     self.step_count += 1
                     continue
 
-                act = np.asarray([r is not None for r in sched.running])
-                if self.hw is not None:
-                    # occupancy signal for the autopilot's load forecast:
-                    # active slots plus queued requests, over capacity
-                    # (>1 = over-subscribed)
-                    self.hw.observe_load(
-                        (int(act.sum()) + len(sched.pending)) / b)
-                pre = act & (slot_pos < plen)
-                dec = act & ~pre
-                # tokens each slot ingests this step (idle slots: none)
-                take = np.where(pre, np.minimum(stride, plen - slot_pos),
-                                act.astype(np.int32))
-                cols = slot_pos[:, None] + arange_c[None, :]     # (B, C)
-                valid = arange_c[None, :] < take[:, None]
-                tok = np.where(
-                    pre[:, None] & valid,
-                    prompt_buf[arange_b[:, None],
-                               np.minimum(cols, buf_len - 1)],
-                    0).astype(np.int32)
-                tok[dec, 0] = last_tok[dec]
-                batch = {"token": jnp.asarray(tok),
-                         "lens": jnp.asarray(self.pool.lens)}
-                if chunk > 1:
-                    batch["n_valid"] = jnp.asarray(
-                        np.maximum(take, 1).astype(np.int32))
-                views = self._gather_views()
-                step_ctx = (self.hw.step(step,
-                                         valid=valid if chunk > 1 else None)
+                with jax.profiler.StepTraceAnnotation("gateway.step",
+                                                      step_num=step):
+                    with span("gateway.admit"):
+                        while (next_arrival < len(todo)
+                               and todo[next_arrival].arrival <= step):
+                            sched.submit(todo[next_arrival], step)
+                            next_arrival += 1
+                        for slot, req in sched.admit(step):
+                            slot_pos[slot] = 0
+                            plen[slot] = req.prompt_len
+                            prompt_buf[slot, :req.prompt_len] = req.prompt
+                            self._reset_slot(slot)
+
+                    with span("gateway.stage"):
+                        act = np.asarray([r is not None
+                                          for r in sched.running])
+                        if self.hw is not None:
+                            # occupancy signal for the autopilot's load
+                            # forecast: active slots plus queued requests,
+                            # over capacity (>1 = over-subscribed)
+                            self.hw.observe_load(
+                                (int(act.sum()) + len(sched.pending)) / b)
+                        pre = act & (slot_pos < plen)
+                        dec = act & ~pre
+                        # tokens each slot ingests this step (idle: none)
+                        take = np.where(
+                            pre, np.minimum(stride, plen - slot_pos),
+                            act.astype(np.int32))
+                        cols = slot_pos[:, None] + arange_c[None, :]  # (B, C)
+                        valid = arange_c[None, :] < take[:, None]
+                        tok = np.where(
+                            pre[:, None] & valid,
+                            prompt_buf[arange_b[:, None],
+                                       np.minimum(cols, buf_len - 1)],
+                            0).astype(np.int32)
+                        tok[dec, 0] = last_tok[dec]
+                        batch = {"token": jnp.asarray(tok),
+                                 "lens": jnp.asarray(self.pool.lens)}
+                        if chunk > 1:
+                            batch["n_valid"] = jnp.asarray(
+                                np.maximum(take, 1).astype(np.int32))
+
+                    with span("gateway.gather"):
+                        views = self._gather_views()
+
+                    with span("gateway.forward"):
+                        step_ctx = (self.hw.step(
+                            step, valid=valid if chunk > 1 else None)
                             if self.hw is not None
                             else contextlib.nullcontext())
-                with step_ctx:
-                    logits, new_kv = self._step_fn(self.params, views, batch)
-                if chunk > 1:
-                    self._scatter_chunk(new_kv, act, take)
-                else:
-                    self._scatter_new(new_kv, list(np.flatnonzero(act)))
-                preds = np.asarray(jnp.argmax(logits, axis=-1))
-                for slot in np.flatnonzero(act):
-                    req = sched.running[slot]
-                    n = int(take[slot])
-                    self.pool.advance(slot, n)
-                    pos = slot_pos[slot] = slot_pos[slot] + n
-                    if pos < plen[slot]:
-                        continue                             # still prefilling
-                    nxt = int(preds[slot])
-                    req.out_tokens.append(nxt)
-                    last_tok[slot] = nxt
-                    self.tokens_out += 1
-                    if req.first_token_step < 0:
-                        req.first_token_step = step
-                    if req.eos_id is not None and nxt == req.eos_id:
-                        sched.finish(slot, step, FINISH_EOS)
-                    elif len(req.out_tokens) >= req.max_new:
-                        sched.finish(slot, step, FINISH_MAX_NEW)
-                self.busy_steps += 1
-                self.slot_steps += int(act.sum())
-                self.step_count += 1
-        wall = time.time() - t0
+                        with step_ctx:
+                            logits, new_kv = self._step_fn(self.params,
+                                                           views, batch)
+
+                    with span("gateway.scatter"):
+                        if chunk > 1:
+                            self._scatter_chunk(new_kv, act, take)
+                        else:
+                            self._scatter_new(new_kv,
+                                              list(np.flatnonzero(act)))
+
+                    with span("gateway.fetch"):
+                        preds = np.asarray(jnp.argmax(logits, axis=-1))
+
+                    with span("gateway.emit"):
+                        # positions each active slot attended over: its
+                        # cache before the step and what it took in
+                        self.kv_live_positions += int(
+                            (self.pool.lens + take)[act].sum())
+                        for slot in np.flatnonzero(act):
+                            req = sched.running[slot]
+                            n = int(take[slot])
+                            self.pool.advance(slot, n)
+                            pos = slot_pos[slot] = slot_pos[slot] + n
+                            if pos < plen[slot]:
+                                continue                 # still prefilling
+                            nxt = int(preds[slot])
+                            req.out_tokens.append(nxt)
+                            last_tok[slot] = nxt
+                            self.tokens_out += 1
+                            if req.first_token_step < 0:
+                                req.first_token_step = step
+                            if req.eos_id is not None and nxt == req.eos_id:
+                                sched.finish(slot, step, FINISH_EOS)
+                            elif len(req.out_tokens) >= req.max_new:
+                                sched.finish(slot, step, FINISH_MAX_NEW)
+                        self.busy_steps += 1
+                        self.slot_steps += int(act.sum())
+                        self.step_count += 1
+        wall = time.perf_counter() - t0
         if not sched.idle:
             raise RuntimeError(
                 f"gateway hit max_steps={self.gcfg.max_steps} with "
