@@ -108,8 +108,10 @@ def kv_kernels_check(seed: int, cfg) -> None:
         idx = jnp.stack([flat // PAGE_SIZE, flat % PAGE_SIZE],
                         1).astype(jnp.int32)
         rows = jax.random.normal(kr, (n,) + shape[2:], jnp.bfloat16)
+        # the scatter consumes (donates) the pool it is given: a copy
         checks[f"{name} of {n} rows"] = (
-            fn(idx, rows, pool), pool.at[idx[:, 0], idx[:, 1]].set(rows))
+            fn(idx, rows, jnp.copy(pool)),
+            pool.at[idx[:, 0], idx[:, 1]].set(rows))
     for name, (got, want) in checks.items():
         if got.shape != want.shape:
             raise AssertionError(f"{name}: shape {got.shape}, jnp indexing "

@@ -72,13 +72,15 @@ def sigma_grad(dy, x, u, v):
                        interpret=default_interpret())
 
 
-def paged_gather(table, pages):
+def paged_gather(table, pages, *, lead=None):
     """Paged-KV page assembly (serving gateway) via the Pallas kernel."""
-    return _paged_gather(table, pages, interpret=default_interpret())
+    return _paged_gather(table, pages, lead=lead,
+                         interpret=default_interpret())
 
 
 def paged_scatter(idx, new, pages):
-    """Paged-KV token insertion (serving gateway) via the Pallas kernel."""
+    """Paged-KV token insertion (serving gateway) via the Pallas kernel;
+    ``pages`` is donated, so the caller keeps only the result."""
     return _paged_scatter(idx, new, pages, interpret=default_interpret())
 
 

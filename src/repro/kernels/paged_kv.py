@@ -16,10 +16,11 @@ data movements per step:
 * **scatter** — write each slot's freshly projected k/v row into its
   current (page, offset) write position, in place: the pool stays in
   HBM (``memory_space=pl.ANY``), aliased into the output
-  (``input_output_aliases``), and each row lands by one DMA.  A pool
-  of real size is hundreds of MB, far beyond VMEM, and a one-row store
-  at a dynamic sublane offset does not lower; a DMA that slices only
-  the page and offset axes does both.
+  (``input_output_aliases``) and donated by the jit around the call,
+  so XLA puts no copy of the pool before it, and each row lands by one
+  DMA.  A pool of real size is hundreds of MB, far beyond VMEM, and a
+  one-row store at a dynamic sublane offset does not lower; a DMA that
+  slices only the page and offset axes does both.
 
 Both kernels take ``interpret`` from ``kernels.ops``, which decides it
 once: interpret mode off-TPU (the kernel bodies run on XLA:CPU),
@@ -44,16 +45,20 @@ def _gather_kernel(tbl_ref, pages_ref, out_ref):
     out_ref[0, 0] = pages_ref[0]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("lead", "interpret"))
 def paged_gather(table: jax.Array, pages: jax.Array, *,
+                 lead: tuple[int, ...] | None = None,
                  interpret: bool = False) -> jax.Array:
     """Assemble per-slot contiguous KV views from a paged pool.
 
     table: (B, J) int32 physical page ids (unallocated entries must
     hold a valid id — 0 by convention; attention masks them by length).
-    pages: (n_pages, page_size, *row).  Returns (B, J·page_size, *row).
-    The table rides flat in SMEM (a 2-D SMEM array pads its rows to
-    128 words).
+    pages: (n_pages, page_size, *row).  Returns (B, J·page_size, *row),
+    or with ``lead`` (whose product is B, e.g. ``(periods, slots)``)
+    ``(*lead, J·page_size, *row)``: the split happens inside this
+    program, where it only relabels major axes, so the view is never
+    copied into a buffer of its own.  The table rides flat in SMEM (a
+    2-D SMEM array pads its rows to 128 words).
     """
     b, j = table.shape
     ps, row = pages.shape[1], pages.shape[2:]
@@ -71,7 +76,7 @@ def paged_gather(table: jax.Array, pages: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((b, j, ps) + row, pages.dtype),
         interpret=interpret,
     )(table.reshape(-1), pages)
-    return out.reshape((b, j * ps) + row)
+    return out.reshape((lead or (b,)) + (j * ps,) + row)
 
 
 def _scatter_kernel(idx_ref, rows_hbm, pages_hbm, out_hbm, sem):
@@ -88,7 +93,8 @@ def _scatter_kernel(idx_ref, rows_hbm, pages_hbm, out_hbm, sem):
     copy.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret",),
+                   donate_argnames=("pages",))
 def paged_scatter(idx: jax.Array, new: jax.Array, pages: jax.Array, *,
                   interpret: bool = False) -> jax.Array:
     """Write one new KV row per slot into its page-table position.
@@ -96,10 +102,12 @@ def paged_scatter(idx: jax.Array, new: jax.Array, pages: jax.Array, *,
     idx: (B, 2) int32 — per slot ``(page_id, offset)`` write position
     (idle slots must point somewhere harmless, e.g. a scratch page).
     new: (B, *row); pages: (n_pages, page_size, *row), updated in place
-    via output aliasing.  Returns the updated pool.  On a TPU ``row``
-    needs two or more axes (e.g. ``(Hkv, Dh)``): the DMAs slice the
-    page and offset axes, and a one-row slice of a tiled (minor two)
-    axis does not lower.
+    via output aliasing.  Returns the updated pool.  ``pages`` is
+    donated, so the kernel writes into its buffer with no copy before
+    it: the pool passed in is consumed, and the caller keeps only the
+    result.  On a TPU ``row`` needs two or more axes (e.g.
+    ``(Hkv, Dh)``): the DMAs slice the page and offset axes, and a
+    one-row slice of a tiled (minor two) axis does not lower.
     """
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
@@ -128,7 +136,8 @@ def paged_scatter_rows(idx: jax.Array, rows: jax.Array, pages: jax.Array, *,
     row, sequentially, so duplicate targets (e.g. every invalid row
     parked on the scratch page) resolve deterministically last-wins —
     and the pool is updated in place through the same
-    ``input_output_aliases`` wiring as the one-row path.
+    ``input_output_aliases`` wiring as the one-row path, and ``pages``
+    is consumed in the same way.
 
     idx: (R, 2) int32; rows: (R, *row); pages: (n_pages, page_size, *row).
     """

@@ -151,25 +151,25 @@ class ServingGateway:
 
     def _gather_views(self) -> dict:
         """Assemble every attention position's (P, B, S_max, Hkv, Dh)
-        views from the pools; SSM positions pass their dense states."""
-        b = self.gcfg.slots
-        jps = self.gcfg.pages.max_pages_per_slot * self.gcfg.pages.page_size
+        views from the pools, each in the gather's own program (no
+        reshape after it, which would copy the view); SSM positions pass
+        their dense states."""
+        lead = (self.n_periods, self.gcfg.slots)
         table = jnp.asarray(self._period_table())
         views = {}
         for name, pools in self._pools.items():
-            hk, hd = self._kv_dims[name]
-            views[name] = {
-                kk: paged_gather(table, pools[kk]).reshape(
-                    self.n_periods, b, jps, hk, hd)
-                for kk in ("k", "v")}
+            views[name] = {kk: paged_gather(table, pools[kk], lead=lead)
+                           for kk in ("k", "v")}
         for name, st in self._ssm.items():
             views[name] = st
         return views
 
     def _scatter_new(self, new_kv: dict, active: Sequence[int]) -> None:
         """Persist each active slot's new KV row at its write position;
-        idle slots land on the scratch page.  SSM replacement states are
-        adopted wholesale (idle slots' states are reset on admit)."""
+        idle slots land on the scratch page.  The scatter consumes the
+        pool it is given, in place, and the result replaces it.  SSM
+        replacement states are adopted wholesale (idle slots' states are
+        reset on admit)."""
         b = self.gcfg.slots
         idx = np.zeros((b, 2), np.int32)
         idx[:, 0] = self._scratch
@@ -333,6 +333,8 @@ class ServingGateway:
                         with step_ctx:
                             logits, new_kv = self._step_fn(self.params,
                                                            views, batch)
+                        # the next gather may reuse these views' memory
+                        del views
 
                     with span("gateway.scatter"):
                         if chunk > 1:

@@ -17,10 +17,12 @@ Three layers of guarantees:
 
 import argparse
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -199,6 +201,41 @@ def test_paged_scatter_matches_reference():
     want = pages.copy()
     for r in range(b):
         want[idx[r, 0], idx[r, 1]] = new[r]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_paged_gather_lead_gives_the_step_view():
+    from repro.kernels.ops import paged_gather
+
+    rng = np.random.default_rng(2)
+    n_pages, ps, hk, dh, p, b, j = 10, 4, 2, 3, 2, 3, 2
+    pages = jnp.asarray(rng.normal(size=(n_pages, ps, hk, dh)), jnp.float32)
+    table = jnp.asarray(rng.integers(0, n_pages, size=(p * b, j)),
+                        jnp.int32)
+    got = paged_gather(table, pages, lead=(p, b))
+    want = np.asarray(pages)[np.asarray(table)].reshape(p, b, j * ps, hk, dh)
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("name", ["paged_scatter", "paged_scatter_rows"])
+def test_paged_scatter_consumes_the_pool(name):
+    """The pool is donated: its buffer becomes the result, so the caller
+    must keep only what the scatter returns."""
+    from repro.kernels import ops, paged_kv
+
+    rng = np.random.default_rng(4)
+    pages = jnp.asarray(rng.normal(size=(6, 4, 2, 3)), jnp.float32)
+    idx = jnp.asarray([[1, 2], [4, 0]], jnp.int32)
+    new = jnp.asarray(rng.normal(size=(2, 2, 3)), jnp.float32)
+    text = paged_kv.paged_scatter.lower(idx, new, pages,
+                                        interpret=True).as_text()
+    assert re.search(r"%arg2: tensor<6x4x2x3xf32> "
+                     r"\{tf\.aliasing_output = 0 : i32\}", text), text
+    want = np.asarray(pages).copy()
+    want[1, 2], want[4, 0] = np.asarray(new)
+    got = getattr(ops, name)(idx, new, pages)
+    assert pages.is_deleted()
     np.testing.assert_array_equal(np.asarray(got), want)
 
 

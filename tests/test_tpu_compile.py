@@ -15,7 +15,9 @@ index map becomes an i64, which Mosaic refuses).
 """
 
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +83,42 @@ def test_paged_scatter_rows_compiles(one_chip, chunk):
         _spec(one_chip, (rows, 2), jnp.int32),
         _spec(one_chip, (rows, HKV, DH), jnp.bfloat16),
         _pool(one_chip, n_periods)).compile()
+
+
+def _bf16_copies(text, shape):
+    """The ``copy`` instructions in compiled HLO ``text`` that move as
+    many bf16 elements as ``shape`` holds, in whatever layout."""
+    return [m.group(0) for m in re.finditer(r"bf16\[([\d,]+)\]\S* copy\(",
+                                            text)
+            if math.prod(map(int, m.group(1).split(","))) == math.prod(shape)]
+
+
+@pytest.mark.parametrize("chunk", [1, 32])
+def test_donated_paged_scatter_rows_writes_the_pool_in_place(one_chip, chunk):
+    """The program ``paged_scatter_rows`` runs (the donated
+    ``paged_scatter``) aliases the pool into its result: no copy of the
+    pool precedes the kernel."""
+    n_periods = 8
+    rows = n_periods * SLOTS * chunk
+    pool = _pool(one_chip, n_periods)
+    compiled = paged_kv.paged_scatter.lower(
+        _spec(one_chip, (rows, 2), jnp.int32),
+        _spec(one_chip, (rows, HKV, DH), jnp.bfloat16), pool,
+        interpret=False).compile()
+    pool_bytes = math.prod(pool.shape) * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    assert _bf16_copies(compiled.as_text(), pool.shape) == []
+
+
+def test_paged_gather_returns_the_step_view_without_a_copy(one_chip):
+    n_periods = 8
+    lowered = paged_kv.paged_gather.lower(
+        _spec(one_chip, (n_periods * SLOTS, PAGES_PER_SLOT), jnp.int32),
+        _pool(one_chip, n_periods), lead=(n_periods, SLOTS),
+        interpret=False)
+    view = (n_periods, SLOTS, PAGES_PER_SLOT * PAGE, HKV, DH)
+    assert lowered.out_info.shape == view
+    assert _bf16_copies(lowered.compile().as_text(), view) == []
 
 
 def test_prefill_attention_compiles_with_derived_block(one_chip):
