@@ -6,9 +6,13 @@
 Compiles, at the cell's sizes, the gateway step (or the chunked prefill
 step), the paged gather and the paged scatter, with the Pallas kernels
 lowered for Mosaic, and prints each program's ``memory_analysis()`` and
-the cell's reckoned device memory: parameters, the two KV pools, two
-steps' gathered views and the largest program's temporaries.  A compile
-that passes is not a chip run and says nothing of times.
+the cell's reckoned device memory: parameters, the KV pools, one step's
+gathered views (the engine drops a step's views before the next
+gather, and its scatter writes into the donated pool, with no copy) and
+the largest program's temporaries.  The pools' rows are those of a
+gateway built at one page (``bench.arch.kv_pool``), as the
+benchmark's readers take them.  A compile that passes is not a chip run
+and says nothing of times.
 """
 
 from __future__ import annotations
@@ -36,10 +40,12 @@ def main(argv=None) -> int:
     from jax.sharding import SingleDeviceSharding
 
     from bench.cell import find_cell
-    from bench.entries.gateway import program_arch
+    from bench.arch import kv_pool, program_arch
     from repro.kernels import ops, paged_kv
     from repro.models.lm import (build_gateway_prefill_step,
                                  build_gateway_step, init_model)
+    from repro.serving.engine import GatewayConfig, ServingGateway
+    from repro.serving.kv_pages import PageConfig
 
     jax.config.update("jax_enable_compilation_cache", False)
     ops.default_interpret = lambda: False       # lower Pallas for Mosaic
@@ -55,27 +61,35 @@ def main(argv=None) -> int:
 
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
         lambda k: init_model(k, arch), jax.random.PRNGKey(0)))
-    layers, b, c = arch.n_layers, mix["slots"], mix["prefill_chunk"]
-    hk, hd, ps = arch.n_kv_heads, arch.hd, mix["page_size"]
-    j = mix["max_pages_per_slot"]
-    pool = sds((layers * (mix["n_pages"] + 1), ps, hk, hd), jnp.bfloat16)
-    view = sds((layers, b, j * ps, hk, hd), jnp.bfloat16)
+    b, c = mix["slots"], mix["prefill_chunk"]
+    ps, j = mix["page_size"], mix["max_pages_per_slot"]
+    one_page = ServingGateway(arch, params, GatewayConfig(
+        slots=1, pages=PageConfig(page_size=ps, n_pages=1,
+                                  max_pages_per_slot=1)))
+    layers, rows = one_page.n_periods, kv_pool(one_page)
+    del one_page
+    pools = {name: {kk: sds((layers * (mix["n_pages"] + 1), ps) + row, dt)
+                    for kk, (row, dt) in t.items()}
+             for name, t in rows.items()}
+    views = {name: {kk: sds((layers, b, j * ps) + row, dt)
+                    for kk, (row, dt) in t.items()}
+             for name, t in rows.items()}
     batch = {"token": sds((b, c), jnp.int32), "lens": sds((b,), jnp.int32)}
     if c > 1:
         batch["n_valid"] = sds((b,), jnp.int32)
         step = build_gateway_prefill_step(arch)
     else:
         step = build_gateway_step(arch)
-    progs = {
-        "step": jax.jit(step).lower(params, {"pos0": {"k": view, "v": view}},
-                                    batch),
-        "gather": paged_kv.paged_gather.lower(
-            sds((layers * b, j), jnp.int32), pool, interpret=False),
-        "scatter": paged_kv.paged_scatter.lower(
-            sds((layers * b * c, 2), jnp.int32),
-            sds((layers * b * c, hk, hd), jnp.bfloat16), pool,
-            interpret=False),
-    }
+    progs = {"step": jax.jit(step).lower(params, views, batch)}
+    for name, t in pools.items():
+        for kk, pool in t.items():
+            progs[f"gather {name}.{kk}"] = paged_kv.paged_gather.lower(
+                sds((layers * b, j), jnp.int32), pool, lead=(layers, b),
+                interpret=False)
+            progs[f"scatter {name}.{kk}"] = paged_kv.paged_scatter.lower(
+                sds((layers * b * c, 2), jnp.int32),
+                sds((layers * b * c,) + pool.shape[2:], pool.dtype), pool,
+                interpret=False)
     temps = {}
     for name, lowered in progs.items():
         m = lowered.compile().memory_analysis()
@@ -84,14 +98,14 @@ def main(argv=None) -> int:
               f"outputs {m.output_size_in_bytes / GIB:.3f} GiB, aliased "
               f"{m.alias_size_in_bytes / GIB:.3f} GiB, temporaries "
               f"{m.temp_size_in_bytes / GIB:.3f} GiB")
-    p_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
-    pools = 2 * pool.size * 2
-    views = 2 * view.size * 2
-    # the engine gathers a step's views while it still holds the last's
-    total = p_bytes + pools + 2 * views + max(temps.values())
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+    p_bytes, pool_bytes, view_bytes = map(nbytes, (params, pools, views))
+    total = p_bytes + pool_bytes + view_bytes + max(temps.values())
     print(f"reckoned device memory: parameters {p_bytes / GIB:.3f} GiB + "
-          f"pools (k, v) {pools / GIB:.3f} GiB + two steps' views (k, v) "
-          f"{2 * views / GIB:.3f} GiB + the largest temporaries "
+          f"pools {pool_bytes / GIB:.3f} GiB + one step's views "
+          f"{view_bytes / GIB:.3f} GiB + the largest temporaries "
           f"{max(temps.values()) / GIB:.3f} GiB = {total / GIB:.3f} GiB "
           f"of the chip's 16 GiB")
     return 0

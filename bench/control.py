@@ -43,11 +43,12 @@ sys.path[:0] = [str(CHECKOUT), str(CHECKOUT / "src")]
 def readings(cell, seed: int, seconds: float) -> dict:
     """One seed: serve a window, then judge the program and the control
     over the run's sample."""
+    from bench.arch import program_arch
     from bench.entries import gateway
 
     cfg, mix = cell.config, cell.mix
     limit = mix["check"]["limit_gap_sigma"]
-    arch = gateway.program_arch(cfg)
+    arch = program_arch(cfg)
     params = gateway.make_params(cfg, arch, seed)
     gw = gateway.build_gateway(arch, params, mix, seed)
     win = gateway.Window()

@@ -24,10 +24,16 @@ token count, has failed; one still running at the cut has not.  The
 collector's heap is frozen before the window, and every collection and
 every pause of the engine inside it is printed.
 
-The traced run (``--trace 1``) starts the profiler at the first token
-of the mix's ``trace.start_s`` and ends its window ``trace.length_s``
-later, so the trace holds a few seconds of steady serving and stays
-small; the per-layer metrics read that span.
+The traced run (``--trace 1``) serves the same window.  It starts the
+profiler at the first token of the mix's ``trace.start_s`` and stops it
+at the first token ``trace.length_s`` later, so the trace holds a few
+seconds of steady serving and stays small; the per-layer metrics read
+that span, but for ``mfu.serve``, which counts the positions served in
+the whole window over its time less the seconds the profiler took to
+stop inside it (``served_s``), as ``tok_s`` counts the untraced
+window.  The readers of the paged KV kernels take the bytes of a
+cached row from the pools the gateway built (``kv_pool``), not from
+the configuration.
 
 ``correct``: once the window has closed and the gateway is freed, a
 sample of the requests that finished in it, drawn from the seed, the
@@ -51,15 +57,16 @@ import time
 import numpy as np
 
 from .. import tracing, traffic
+from ..arch import kv_pool, program_arch
 from ..cell import Cell, load_metric, load_reference
 from ..compiles import CompileClock
 from ..peaks import peaks_for
 from ..weights import make_weights, seed_key
 
-__all__ = ["run", "program_arch", "make_params", "build_gateway",
-           "queue_requests", "serve", "failed", "ingested", "positions",
-           "tpot_spans", "sample", "reference_gaps", "judge", "Window",
-           "StampedTokens", "DeadlineStop", "MetricContext"]
+__all__ = ["run", "make_params", "build_gateway", "queue_requests", "serve",
+           "failed", "ingested", "positions", "tpot_spans", "sample",
+           "reference_gaps", "judge", "Window", "StampedTokens",
+           "DeadlineStop", "MetricContext"]
 
 SPAN_WINDOW = "bench.window"
 SPAN_TRACED = "bench.traced"
@@ -126,34 +133,7 @@ class MetricContext:
     lo: float                   # traced span on the trace clock, ns
     hi: float
     window_s: float             # its length, seconds
-    counts: dict                # the engine's counts over that span
-
-
-def program_arch(cfg: dict):
-    """The program's ArchConfig for a configuration file: its named
-    arch with the file's sizes."""
-    from repro.configs import get_config
-
-    base = get_config(cfg["arch"])
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    arch = dataclasses.replace(
-        base, n_layers=cfg["num_hidden_layers"], d_model=d, n_heads=h,
-        n_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim") or d // h,
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        ptc=dataclasses.replace(base.ptc, k=cfg["assumed"]["ptc_block"]))
-    want = {"norm": {"rmsnorm": "rmsnorm",
-                     "layernorm_nonparam": "nonparam"}[
-                         cfg["assumed"]["norm"]],
-            "qk_norm": bool(cfg["assumed"]["qk_norm"]),
-            "tie_embed": bool(cfg["tie_word_embeddings"]),
-            "family": "dense", "n_experts": 0}
-    got = {k: getattr(arch, k) for k in want}
-    if got != want:
-        raise ValueError(f"{cfg['arch']}: the program's arch has {got}, the "
-                         f"configuration file states {want}")
-    return arch
+    counts: dict                # the engine's counts (``run``)
 
 
 def build_gateway(arch, params, mix: dict, seed: int):
@@ -407,13 +387,20 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, marks: dict,
     span: dict = {}
 
     def start_trace():
-        # snapshot the counts, then trace from here to a new deadline
-        span["counts"] = (gw.busy_steps, gw.slot_steps,
-                          positions(served, gw.step_count, chunk))
+        # snapshot the counts, trace, and stop trace.length_s from here
+        span["counts"] = [(gw.busy_steps, gw.slot_steps)]
         tracing.start(trace_dir)
         span["annotation"] = jax.profiler.TraceAnnotation(SPAN_TRACED)
         span["annotation"].__enter__()
-        win.deadline = time.perf_counter() + mix["trace"]["length_s"]
+        win.hook_at = time.perf_counter() + mix["trace"]["length_s"]
+        win.hook = stop_trace
+
+    def stop_trace():
+        t = time.perf_counter()
+        span["counts"].append((gw.busy_steps, gw.slot_steps))
+        span["annotation"].__exit__(None, None, None)
+        tracing.stop()
+        span["stop_s"] = time.perf_counter() - t
 
     if trace:
         win.hook_after = max(0.0, min(mix["trace"]["start_s"],
@@ -427,12 +414,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, marks: dict,
         if "annotation" not in span:
             raise RuntimeError("the window closed before the traced span "
                                "began: no token came after trace.start_s")
-        span["annotation"].__exit__(None, None, None)
-        tracing.stop()
+        if "stop_s" not in span:        # the window closed first
+            stop_trace()
+            span["stop_s"] = 0.0
     cut_step = gw.step_count
     window_compiles = clock.count - n_compiles
-    end_counts = (gw.busy_steps, gw.slot_steps,
-                  positions(served, cut_step, chunk))
+    pool, n_periods = kv_pool(gw), gw.n_periods
     peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
     del gw
     gc.unfreeze()
@@ -459,10 +446,16 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, marks: dict,
     if trace:
         tr = tracing.load(trace_dir)
         lo, hi = tr.window(SPAN_TRACED)
-        b0, s0, p0 = span["counts"]
-        b1, s1, p1 = end_counts
+        (b0, s0), (b1, s1) = span["counts"]
+        # steps over the traced span; positions served in the window, and
+        # its time less the profiler's stop; the pool's rows and the
+        # layers a gather or scatter call covers
         counts = {"slots": mix["slots"], "busy_steps": b1 - b0,
-                  "slot_steps": s1 - s0, "spans": list(zip(p0, p1))}
+                  "slot_steps": s1 - s0,
+                  "spans": [(0, p) for p in positions(served, cut_step,
+                                                      chunk)],
+                  "served_s": t1 - t0 - span["stop_s"], "kv_pool": pool,
+                  "kv_layers": n_periods}
         mctx = MetricContext(cfg=cfg, mix=mix, peaks=peaks, trace=tr, lo=lo,
                              hi=hi, window_s=(hi - lo) / 1e9, counts=counts)
         for m in cell.per_layer:
@@ -498,6 +491,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, marks: dict,
               f"({s}, {at - t0:.3f}, {d * 1e3:.1f})" for s, at, d in lost[:5]),
           file=log)
     print(f"cycle collector in the window: {gc_log.summary()}", file=log)
+    if trace:
+        print(f"profiler stop inside the window: {span['stop_s']:.3f} s",
+              file=log)
     print(f"reference over {len(picked)} requests, {gaps.size} served "
           f"tokens, {time.perf_counter() - t:.3f} s", file=log)
     result.update(correct=correct, attempted=len(started), failed=len(bad),

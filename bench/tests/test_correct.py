@@ -7,11 +7,14 @@ program broken underneath where a test says so."""
 
 import json
 import time
+import types
 from pathlib import Path
 
 import jax
 import pytest
 
+from bench import tracing, traffic
+from bench.arch import program_arch
 from bench.cell import Cell
 from bench.entries import gateway
 from bench.faults import FAULTS, plant
@@ -57,7 +60,7 @@ def test_window_cut_at_the_deadline_counts_exactly(monkeypatch):
     monkeypatch.setattr(gateway, "peaks_for", lambda kind: {})
     cell = tiny_cell("prompt")
     cfg, mix = cell.config, cell.mix
-    arch = gateway.program_arch(cfg)
+    arch = program_arch(cfg)
     params = gateway.make_params(cfg, arch, 3)
     gw = gateway.build_gateway(arch, params, mix, 3)
     win = gateway.Window()
@@ -105,3 +108,44 @@ def test_fp8_control_exceeds_the_limit(kind, monkeypatch):
     assert r["program_max_gap"] <= tiny_cell(kind).mix["check"][
         "limit_gap_sigma"] < r["control_max_gap"]
     assert r["tokens"] > 0 and r["failed"] == 0
+
+
+def test_traced_run_serves_the_whole_window(monkeypatch):
+    """With ``--trace 1`` the window runs on after the profiler stops:
+    the readers get the traced span for the trace and, for
+    ``mfu.serve``, every position served in the window over its time
+    less the profiler's stop."""
+    seen = {}
+
+    def reader(ctx):
+        seen["ctx"] = ctx
+        return 1.0
+
+    # the profiler's start and stop on the host clock, as the trace's span
+    marks = []
+    stamp = lambda *a: marks.append(time.perf_counter_ns())  # noqa: E731
+    trace = lambda d: tracing.Trace(  # noqa: E731
+        ops={}, modules={},
+        spans=[[gateway.SPAN_TRACED, marks[0], marks[1] - marks[0]]])
+    monkeypatch.setattr(gateway, "tracing", types.SimpleNamespace(
+        start=stamp, stop=stamp, load=trace,
+        busy_seconds=lambda *a: 0.0, top_ops=lambda *a: [],
+        idle_gaps=lambda *a: []))
+    monkeypatch.setattr(gateway, "peaks_for", lambda kind: {})
+    monkeypatch.setattr(gateway, "load_metric", lambda name:
+                        types.SimpleNamespace(read=reader))
+    cell = tiny_cell("decode")
+    cell.per_layer = [{"name": "mfu.serve", "unit": "%"}]
+    cell.mix["trace"] = {"start_s": 0.05, "length_s": 0.05}
+    now = time.perf_counter()
+    r = gateway.run(cell, 2 ** 31 + 11, 60.0, True,
+                    {"start": now, "devices": now}, jax.devices())
+    assert r["correct"], r["checks"]
+    ctx = seen["ctx"]
+    # the queue drained long after the traced span: every position of
+    # every request counts, over more than the span's time
+    sizes = traffic.queue_sizes(cell.mix)
+    assert sorted(p for _, p in ctx.counts["spans"]) == sorted(
+        traffic.tokens_processed(p, n) for p, n in sizes)
+    assert ctx.window_s < ctx.counts["served_s"]
+    assert r["device"]["window_s"] == ctx.window_s

@@ -110,23 +110,39 @@ def test_recorded_trace_breakdown(recorded):
 def test_readers_on_the_recorded_trace(recorded):
     """Every per-layer reader of the azure-conv cell gives a number on
     a real trace of that cell's shapes, and no share passes 100%."""
+    import jax.numpy as jnp
+
     from bench.cell import find_cell, load_metric
     from bench.entries.gateway import MetricContext
     from bench.peaks import peaks_for
 
     cell = find_cell("olmo-1b.azure-conv")
     lo, hi = recorded.window("bench.round")
-    # the traced steps served 12 requests of 40 prompt tokens and 3 new
+    # the traced steps served 12 requests of 40 prompt tokens and 3 new,
+    # from a pool of 16 layers of (16 heads x 128) bf16 rows for k and v
     ctx = MetricContext(cfg=cell.config, mix=cell.mix,
                         peaks=peaks_for("TPU v5 lite"), trace=recorded,
                         lo=lo, hi=hi, window_s=(hi - lo) / 1e9,
                         counts={"slots": 12, "busy_steps": 4,
-                                "slot_steps": 48, "spans": [(0, 42)] * 12})
+                                "slot_steps": 48, "spans": [(0, 42)] * 12,
+                                "served_s": (hi - lo) / 1e9,
+                                "kv_pool": {"pos0": {
+                                    "k": ((16, 128), jnp.bfloat16),
+                                    "v": ((16, 128), jnp.bfloat16)}},
+                                "kv_layers": 16})
     got = {m["name"]: load_metric(m["name"]).read(ctx)
            for m in cell.per_layer}
     assert all(v is not None for v in got.values()), got
     assert got["occupancy.serve"] == pytest.approx(100.0)
     assert got["step_ms.serve"] == pytest.approx(218.332319 / 4, rel=1e-6)
+    # 64 calls of (12 x 32) queries of 16 heads of 128 against (12, 2048)
+    # views of 16 x 128 for k and v, bf16: 4·b·h·c·s·dh FLOPs; q, out and
+    # both views read or written
+    f = 4 * 12 * 16 * 32 * 2048 * 128
+    b = 2 * (2 * 12 * 32 * 16 * 128 + 2 * 12 * 2048 * 16 * 128)
+    t = max(f / 197e12, b / 819e9)
+    assert got["prefill_attn_roofline"] == pytest.approx(
+        100.0 * 64 * t / 0.059613675, rel=1e-5)
     for name, v in got.items():
         if name != "step_ms.serve":
             assert 0.0 < v <= 100.0, (name, v)

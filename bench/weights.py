@@ -10,9 +10,13 @@ Every value comes from here, in one jitted call, in the dtype served:
 * the embedding table (``e``): normal / sqrt(d), times the
   configuration's ``embed_scale``, so that at random init the context,
   and not the last token alone, decides each argmax;
-* norm gains (``g``) one, biases (``b``) zero.
+* norm gains (``g``) one, biases (``b``) zero;
+* any other floating-point leaf (a router, an untied unembedding, a
+  correction bias): at rank 2 and up normal x (its last axis)^-1/2,
+  drawn in float32; at rank 1, zero.
 
-The reference reads these same arrays, never the program's.
+Leaf i of the flattened layout draws from ``fold_in(key, i)``.  The
+reference reads these same arrays, never the program's.
 """
 
 from __future__ import annotations
@@ -48,9 +52,12 @@ def _leaf(key, name: str, sds, d_model: int, embed_scale: float):
                 * (d_model ** -0.5 * embed_scale)).astype(dtype)
     if name == "g":
         return jnp.ones(shape, dtype)
-    if name == "b":
+    if not jnp.issubdtype(dtype, jnp.floating):
+        raise ValueError(f"no rule for a {dtype} weight leaf named {name!r}")
+    if name == "b" or len(shape) < 2:
         return jnp.zeros(shape, dtype)
-    raise ValueError(f"no rule for a weight leaf named {name!r}")
+    return (jax.random.normal(key, shape, jnp.float32)
+            * shape[-1] ** -0.5).astype(dtype)
 
 
 def make_weights(key: jax.Array, layout, d_model: int, embed_scale: float):
