@@ -12,6 +12,13 @@ another value than the file's silence means (``unset_must_be``, else
 the ``ArchConfig`` default), such as the experts of an arch whose file
 states none.
 
+A key that a mapping file maps under ``keys`` goes through that
+mapping, even where another file lists it under ``runs``: ``runs``
+guards a key only while no file maps it.  So a configuration that
+states another norm epsilon than the one the program runs brings a
+file that maps the key onto the program's field, and every file that
+states the key then sets that field.
+
 ``kv_pool`` reads the row shape and dtype of each attention pool tensor
 of a gateway the program built.
 """
@@ -86,13 +93,12 @@ def program_arch(cfg: dict, keys: dict | None = None):
     base = configs.get_config(cfg["arch"])
     fields, nested, refused = {}, {}, []
     for key, value in _stated(cfg, keys):
-        if key in keys["runs"]:
-            if value != keys["runs"][key]:
+        if key not in keys["keys"]:
+            if key not in keys["runs"]:
+                refused.append(f"{key} (not mapped)")
+            elif value != keys["runs"][key]:
                 refused.append(f"{key}={value!r} (the program runs "
                                f"{keys['runs'][key]!r})")
-            continue
-        if key not in keys["keys"]:
-            refused.append(f"{key} (not mapped)")
             continue
         for field, conv in keys["keys"][key].items():
             outer, _, inner = field.partition(".")

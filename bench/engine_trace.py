@@ -3,13 +3,17 @@
 ``ServingGateway.run`` wraps each busy step in a ``gateway.step`` span
 holding one span per phase (``gateway.admit``, ``stage``, ``gather``,
 ``forward``, ``scatter``, ``fetch``, ``emit``), and the model's named
-scopes (``s{i}.attn``, ``s{i}.mlp`` and the PTC leaves ``wq wk wv wo
-gate up down``) reach each op's HLO ``op_name``.  These functions read
-both from a ``tracing.Trace`` whose ``spans`` hold the engine's spans
-beside the harness's, and from a map of the step program's instructions
-to their ``op_name`` (``op_scopes``), built after the window by
-``step_scopes``.  Like ``bench/tracing.py`` they are arithmetic, tested
-on made-up traces.
+scopes (``s{i}.attn``, ``s{i}.mlp`` and one per PTC linear, named as
+its leaf of the parameters) reach each op's HLO ``op_name``.  These
+functions read both from a ``tracing.Trace`` whose ``spans`` hold the
+engine's spans beside the harness's, and from a map of the step
+program's instructions to their ``op_name`` (``op_scopes``), built from
+the engine's own step function by ``step_scopes``.  A reader names its
+layer by a pattern
+that one scope of an op's name matches (``s{i}.attn``); the PTC
+leaves come from the parameters' layout (``ptc_leaves``), so a model
+with other linears needs no edit here.  Like ``bench/tracing.py`` they
+are arithmetic, tested on made-up traces.
 """
 
 from __future__ import annotations
@@ -19,16 +23,16 @@ import re
 from . import tracing
 
 __all__ = ["PHASES", "step_self_ms", "phase_ms",
-           "idle_placed", "op_scopes", "layer_of", "layer_seconds",
-           "layer_ms", "step_scopes"]
+           "idle_placed", "op_scopes", "ptc_leaves", "leaf_pattern",
+           "in_layer", "absent_ops", "layer_seconds", "layer_ms",
+           "step_scopes"]
 
 STEP = "gateway.step"
 PHASES = ("gateway.admit", "gateway.stage", "gateway.gather",
           "gateway.forward", "gateway.scatter", "gateway.fetch",
           "gateway.emit")
 FETCH = "gateway.fetch"
-LEAVES = frozenset(("wq", "wk", "wv", "wo", "gate", "up", "down"))
-ATTN = re.compile(r"^s\d+\.attn$")
+PTC_FACTORS = frozenset(("u", "s", "v"))
 STEP_PROGRAM = r"^jit_(gateway_step|prefill_step)\b"
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
@@ -110,86 +114,114 @@ def op_scopes(hlo_text: str, program: str) -> dict:
     return {f"jit_{program}/{name}": resolve(name) for name in own}
 
 
-def layer_of(op_name: str) -> str | None:
-    """``"ptc"`` for an op under a PTC leaf scope, ``"attn"`` for one
-    under an ``s{i}.attn`` scope and no leaf, else None."""
+def ptc_leaves(layout) -> frozenset:
+    """The names of the PTC linears in a parameter layout: every dict
+    key whose value holds the factors ``u``, ``s`` and ``v``."""
+    out = set()
+
+    def walk(tree):
+        for key, value in tree.items():
+            if isinstance(value, dict):
+                if PTC_FACTORS <= value.keys():
+                    out.add(key)
+                else:
+                    walk(value)
+
+    walk(layout)
+    return frozenset(out)
+
+
+def leaf_pattern(leaves) -> str | None:
+    """A scope pattern matching any of ``leaves``; None for none."""
+    if not leaves:
+        return None
+    return "^(?:" + "|".join(map(re.escape, sorted(leaves))) + ")$"
+
+
+def in_layer(op_name: str, pattern: str, exclude=frozenset()) -> bool:
+    """Whether some scope of ``op_name`` matches ``pattern`` and none is
+    one of ``exclude`` (the PTC leaves, for a layer without them)."""
     parts = op_name.split("/")
-    if LEAVES.intersection(parts):
-        return "ptc"
-    if any(ATTN.match(p) for p in parts):
-        return "attn"
-    return None
+    rx = re.compile(pattern)
+    return (frozenset(exclude).isdisjoint(parts)
+            and any(rx.match(p) for p in parts))
 
 
-def layer_seconds(trace: tracing.Trace, scopes: dict, lo: float, hi: float
-                  ) -> tuple[dict, int]:
-    """({"attn": s, "ptc": s, "other": s}, ops absent from ``scopes``):
-    device seconds of the step program's ops in [lo, hi] by layer,
-    summed over the device planes; loops and calls are left out, as
+def _step_ops(trace: tracing.Trace, lo: float, hi: float):
+    """The step program's ops in [lo, hi] on every device plane, as
+    (name, start, end) clipped to it; loops and calls are left out, as
     their time is that of the ops they hold."""
-    out = {"attn": 0.0, "ptc": 0.0, "other": 0.0}
-    absent = 0
     step_op = re.compile(STEP_PROGRAM + "/")
     for evs in trace.ops.values():
         for name, s, e in tracing._clip(evs, lo, hi):
-            if not step_op.match(name) or tracing.CONTAINER.search(name):
-                continue
-            if name not in scopes:
-                absent += 1
-                continue
-            out[layer_of(scopes[name]) or "other"] += (e - s) / 1e9
-    return out, absent
+            if step_op.match(name) and not tracing.CONTAINER.search(name):
+                yield name, s, e
 
 
-def layer_ms(ctx, layer: str):
-    """Device ms of ``layer`` (``"attn"`` or ``"ptc"``) per run of the
-    step program in a reader's context; None without a scope map
-    (``ctx.scopes``) in which some op carries a layer scope."""
+def absent_ops(trace: tracing.Trace, scopes: dict, lo: float,
+               hi: float) -> int:
+    """How many of the step program's ops in [lo, hi] the scope map
+    does not know: 0 where the map is that of the program that ran."""
+    return sum(name not in scopes for name, _, _ in _step_ops(trace, lo, hi))
+
+
+def layer_seconds(trace: tracing.Trace, scopes: dict, lo: float, hi: float,
+                  pattern: str, exclude=frozenset()) -> tuple[float, int]:
+    """(device seconds of the step program's ops in [lo, hi] that lie
+    in the layer, as ``in_layer`` decides, summed over the device
+    planes; ops absent from ``scopes``)."""
+    inside = {name for name, op in scopes.items()
+              if in_layer(op, pattern, exclude)}
+    seconds, absent = 0.0, 0
+    for name, s, e in _step_ops(trace, lo, hi):
+        if name not in scopes:
+            absent += 1
+        elif name in inside:
+            seconds += (e - s) / 1e9
+    return seconds, absent
+
+
+def layer_ms(ctx, pattern: str | None, exclude_ptc: bool = False):
+    """Device ms of a layer per run of the step program in a reader's
+    context: ops with a scope matching ``pattern`` and, with
+    ``exclude_ptc``, none of the PTC leaves (``ctx.ptc_leaves``).  None
+    without a pattern, without a scope map (``ctx.scopes``) in which
+    some op lies in the layer, and where the map misses any op of the
+    step program in the span: it is then not the map of the program
+    that ran, and the layer would read low."""
     scopes = getattr(ctx, "scopes", None)
-    if not scopes or not any(map(layer_of, scopes.values())):
+    exclude = getattr(ctx, "ptc_leaves", frozenset()) if exclude_ptc else ()
+    if not pattern or not scopes or not any(
+            in_layer(op, pattern, exclude) for op in scopes.values()):
         return None
     _, n = tracing.module_runs(ctx.trace, STEP_PROGRAM, ctx.lo, ctx.hi)
     if not n:
         return None
-    seconds, _ = layer_seconds(ctx.trace, scopes, ctx.lo, ctx.hi)
-    return 1e3 * seconds[layer] / n
+    seconds, absent = layer_seconds(ctx.trace, scopes, ctx.lo, ctx.hi,
+                                    pattern, exclude)
+    if absent:
+        return None
+    return 1e3 * seconds / n
 
 
-def step_scopes(arch, params, mix: dict) -> dict:
-    """The scope map of a serving cell's step program, lowered at the
-    cell's fixed shapes as the engine calls it (the one-token
-    ``gateway_step``, or ``prefill_step`` at a prefill chunk over 1) and
-    compiled, which loads the program from the persistent cache where
-    the run compiled it.  Call it after the window: it traces the
-    model."""
+def step_scopes(gw) -> dict:
+    """The scope map of the step program a serving gateway runs: its own
+    jitted step function, lowered at the shapes of the views it gathers
+    (``_gather_views``, traced without running) and of one step's batch
+    (``slots`` x the prefill chunk), and compiled.  Where the persistent
+    cache holds that program the compile is a load; the instructions
+    and their scopes are those of the program the engine ran, and
+    ``absent_ops`` shows where they are not.  Call it after the window:
+    it traces the model."""
     import jax
     import jax.numpy as jnp
 
-    from repro.models.lm import (build_gateway_prefill_step,
-                                 build_gateway_step, period_plan)
-
-    dev = next(iter(jax.tree.leaves(params)[0].devices()))
-    sharding = jax.sharding.SingleDeviceSharding(dev)
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-    plan, n_periods = period_plan(arch)
-    b, c = mix["slots"], mix["prefill_chunk"]
-    s_max = mix["max_pages_per_slot"] * mix["page_size"]
-    views = {}
-    for i, sub in enumerate(plan):
-        if sub.kind != "attn":
-            raise ValueError("serving cells run attention-only archs")
-        acfg = arch.attn_cfg(sub.window)
-        view = sds((n_periods, b, s_max, acfg.n_kv_heads, acfg.head_dim),
-                   jnp.bfloat16)
-        views[f"pos{i}"] = {"k": view, "v": view}
-    batch = {"token": sds((b, c), jnp.int32), "lens": sds((b,), jnp.int32)}
+    b, c = gw.gcfg.slots, gw.chunk
+    batch = {"token": jax.ShapeDtypeStruct((b, c), jnp.int32),
+             "lens": jax.ShapeDtypeStruct((b,), jnp.int32)}
     if c > 1:
-        batch["n_valid"] = sds((b,), jnp.int32)
-        step, program = build_gateway_prefill_step(arch), "prefill_step"
-    else:
-        step, program = build_gateway_step(arch), "gateway_step"
-    text = jax.jit(step).lower(params, views, batch).compile().as_text()
-    return op_scopes(text, program)
+        batch["n_valid"] = jax.ShapeDtypeStruct((b,), jnp.int32)
+    views = jax.eval_shape(gw._gather_views)
+    lowered = gw._step_fn.lower(gw.params, views, batch)
+    program = "prefill_step" if c > 1 else "gateway_step"
+    return op_scopes(lowered.compile().as_text(), program)

@@ -33,7 +33,13 @@ the whole window over its time less the seconds the profiler took to
 stop inside it (``served_s``), as ``tok_s`` counts the untraced
 window.  The readers of the paged KV kernels take the bytes of a
 cached row from the pools the gateway built (``kv_pool``), not from
-the configuration.
+the configuration.  Every public integer attribute of the gateway
+(its counters, ``engine_counters``) reaches the readers as its
+difference across the traced span, under its own name, and the scope
+map of the step program (``engine_trace.step_scopes``, from the
+engine's own step function after the window) with the PTC leaves of the
+parameters, so a counter or a scope
+that a later engine or model adds needs only a reader of its own.
 
 ``correct``: once the window has closed and the gateway is freed, a
 sample of the requests that finished in it, drawn from the seed, the
@@ -49,6 +55,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import math
+import numbers
 import shutil
 import sys
 import tempfile
@@ -56,7 +63,7 @@ import time
 
 import numpy as np
 
-from .. import tracing, traffic
+from .. import engine_trace, tracing, traffic
 from ..arch import kv_pool, program_arch
 from ..cell import Cell, load_metric, load_reference
 from ..compiles import CompileClock
@@ -65,8 +72,8 @@ from ..weights import make_weights, seed_key
 
 __all__ = ["run", "make_params", "build_gateway", "queue_requests", "serve",
            "failed", "ingested", "positions", "tpot_spans", "sample",
-           "reference_gaps", "judge", "Window", "StampedTokens",
-           "DeadlineStop", "MetricContext"]
+           "reference_gaps", "judge", "engine_counters", "counts_across",
+           "Window", "StampedTokens", "DeadlineStop", "MetricContext"]
 
 SPAN_WINDOW = "bench.window"
 SPAN_TRACED = "bench.traced"
@@ -133,7 +140,36 @@ class MetricContext:
     lo: float                   # traced span on the trace clock, ns
     hi: float
     window_s: float             # its length, seconds
-    counts: dict                # the engine's counts (``run``)
+    # the engine's counts (``run``): each public integer attribute of the
+    # gateway as its difference across the traced span, so a setting
+    # (``chunk``, ``stride``, ``n_periods``) reads 0; then ``slots``,
+    # ``spans``, ``served_s``, ``kv_pool`` and ``kv_layers``
+    counts: dict
+    # the step program's {op: HLO op_name} (engine_trace.step_scopes)
+    scopes: dict = dataclasses.field(default_factory=dict)
+    ptc_leaves: frozenset = frozenset()     # engine_trace.ptc_leaves
+
+
+def engine_counters(gw) -> dict:
+    """Every public integer attribute of the gateway: its counters
+    (``step_count``, ``busy_steps``, ``slot_steps``,
+    ``kv_live_positions``, ``tokens_out``, and any a later engine adds)
+    and its integer settings (``chunk``, ``stride``, ``n_periods``),
+    which read 0 as a difference."""
+    return {k: v for k, v in vars(gw).items()
+            if not k.startswith("_") and isinstance(v, numbers.Integral)
+            and not isinstance(v, bool)}
+
+
+def counts_across(before: dict, after: dict, **fixed) -> dict:
+    """Each counter's difference from ``before`` to ``after`` (so a
+    setting reads 0), under its own name, then ``fixed``; a name in
+    both is an error, as one would hide the other."""
+    both = sorted(set(after) & set(fixed))
+    if both:
+        raise ValueError(f"the gateway's counters {both} have the names "
+                         f"of the harness's counts")
+    return {**{k: v - before.get(k, 0) for k, v in after.items()}, **fixed}
 
 
 def build_gateway(arch, params, mix: dict, seed: int):
@@ -387,8 +423,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, marks: dict,
     span: dict = {}
 
     def start_trace():
-        # snapshot the counts, trace, and stop trace.length_s from here
-        span["counts"] = [(gw.busy_steps, gw.slot_steps)]
+        # snapshot the counters and the slots' cached lengths, trace, and
+        # stop trace.length_s from here
+        span["counters"] = [engine_counters(gw)]
+        span["lens"] = np.array(gw.pool.lens)
         tracing.start(trace_dir)
         span["annotation"] = jax.profiler.TraceAnnotation(SPAN_TRACED)
         span["annotation"].__enter__()
@@ -397,7 +435,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, marks: dict,
 
     def stop_trace():
         t = time.perf_counter()
-        span["counts"].append((gw.busy_steps, gw.slot_steps))
+        span["counters"].append(engine_counters(gw))
         span["annotation"].__exit__(None, None, None)
         tracing.stop()
         span["stop_s"] = time.perf_counter() - t
@@ -421,6 +459,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, marks: dict,
     window_compiles = clock.count - n_compiles
     pool, n_periods = kv_pool(gw), gw.n_periods
     peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    if trace:
+        t = time.perf_counter()
+        scopes = engine_trace.step_scopes(gw)
+        scopes_s = time.perf_counter() - t
     del gw
     gc.unfreeze()
     gc.collect()
@@ -446,18 +488,27 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, marks: dict,
     if trace:
         tr = tracing.load(trace_dir)
         lo, hi = tr.window(SPAN_TRACED)
-        (b0, s0), (b1, s1) = span["counts"]
-        # steps over the traced span; positions served in the window, and
-        # its time less the profiler's stop; the pool's rows and the
-        # layers a gather or scatter call covers
-        counts = {"slots": mix["slots"], "busy_steps": b1 - b0,
-                  "slot_steps": s1 - s0,
-                  "spans": [(0, p) for p in positions(served, cut_step,
-                                                      chunk)],
-                  "served_s": t1 - t0 - span["stop_s"], "kv_pool": pool,
-                  "kv_layers": n_periods}
+        # the counters over the traced span; positions served in the
+        # window, and its time less the profiler's stop; the pool's rows
+        # and the layers a gather or scatter call covers
+        counts = counts_across(
+            *span["counters"], slots=mix["slots"],
+            spans=[(0, p) for p in positions(served, cut_step, chunk)],
+            served_s=t1 - t0 - span["stop_s"], kv_pool=pool,
+            kv_layers=n_periods)
+        lens = span["lens"][span["lens"] > 0]
+        print(f"traced span from engine step "
+              f"{span['counters'][0]['step_count']}, {lens.size} slots "
+              f"holding {lens.mean() if lens.size else 0:.1f} cached "
+              f"positions on average; scope map of the step program: "
+              f"{len(scopes)} instructions, {scopes_s:.3f} s, step-program "
+              f"ops in the span absent from it: "
+              f"{engine_trace.absent_ops(tr, scopes, lo, hi)}",
+              file=sys.stderr)
         mctx = MetricContext(cfg=cfg, mix=mix, peaks=peaks, trace=tr, lo=lo,
-                             hi=hi, window_s=(hi - lo) / 1e9, counts=counts)
+                             hi=hi, window_s=(hi - lo) / 1e9, counts=counts,
+                             scopes=scopes,
+                             ptc_leaves=engine_trace.ptc_leaves(params))
         for m in cell.per_layer:
             v = load_metric(m["name"]).read(mctx)
             if v is not None:

@@ -60,6 +60,10 @@ def enable_cache() -> str:
     # by the next run in this checkout
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # key entries on the HLO metadata too: a step program cached without
+    # the model's named scopes would otherwise be loaded for one with
+    # them, and the scope map (engine_trace.step_scopes) would find none
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return where
 
 

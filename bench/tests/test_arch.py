@@ -49,24 +49,66 @@ def refusals(cfg: dict, keys: dict | None = None) -> dict:
     return {re.split("[ =]", part, 1)[0]: part for part in said.split("; ")}
 
 
-def test_program_arch_names_what_it_cannot_take():
+@pytest.fixture
+def base_keys(tmp_path) -> dict:
+    """The mapping of ``base.json`` alone: what the base refuses stays
+    refused here, whatever mapping files later configurations add."""
+    shutil.copy(KEYS_DIR / "base.json", tmp_path)
+    return load_keys(tmp_path)
+
+
+def left_to_the_arch(cfg: dict, keys: dict) -> set:
+    """The fields of the program's arch for ``cfg`` that hold another
+    value than the file's silence means and that no key it states sets:
+    what ``program_arch`` names besides the keys it cannot take."""
+    from repro.configs import get_config
+
+    stated = set(cfg) | {f"{group}.{k}" for group, v in cfg.items()
+                         if isinstance(v, dict) for k in v}
+    set_by_keys = {field.partition(".")[0]
+                   for key in stated & set(keys["keys"])
+                   for field in keys["keys"][key]}
+    base = get_config(cfg["arch"])
+    out = set()
+    for f in dataclasses.fields(base):
+        if (f.name in set_by_keys or f.name in keys["policy"]
+                or f.name in keys["unstated"]):
+            continue
+        default = f.default
+        if f.default_factory is not dataclasses.MISSING:
+            default = f.default_factory()
+        if getattr(base, f.name) != keys["unset_must_be"].get(f.name,
+                                                              default):
+            out.add(f.name)
+    return out
+
+
+def test_program_arch_names_what_it_cannot_take(base_keys):
     cfg = dict(config("olmo-1b"), kv_lora_rank=512)
-    assert refusals(cfg) == {"kv_lora_rank": "kv_lora_rank (not mapped)"}
+    assert refusals(cfg, base_keys) == {
+        "kv_lora_rank": "kv_lora_rank (not mapped)"}
     cfg = dict(config("qwen3-4b"), rms_norm_eps=1e-5)
-    assert refusals(cfg) == {
+    assert refusals(cfg, base_keys) == {
         "rms_norm_eps": "rms_norm_eps=1e-05 (the program runs 1e-06)"}
     # a key of a group that is neither mapped nor inert
     cfg = dict(config("qwen3-4b"), rope_scaling={"type": "yarn"})
-    assert list(refusals(cfg)) == ["rope_scaling.type"]
+    assert list(refusals(cfg, base_keys)) == ["rope_scaling.type"]
 
 
-def test_program_arch_refuses_what_the_file_leaves_to_the_arch():
+def test_program_arch_refuses_what_the_file_leaves_to_the_arch(base_keys):
     """A file naming an arch with experts, but stating none, does not
-    run the arch's experts: each such field is named."""
+    run the arch's experts: each such field is named, with the value the
+    program's arch holds."""
+    from repro.configs import get_config
+
     cfg = dict(config("olmo-1b"), arch="moonshot-v1-16b-a3b")
-    said = refusals(cfg)
-    assert set(said) == {"family", "n_experts", "top_k"}
-    assert said["n_experts"].startswith("n_experts=64 in the program's arch")
+    said = refusals(cfg, base_keys)
+    want = left_to_the_arch(cfg, base_keys)
+    assert want and set(said) == want
+    base = get_config(cfg["arch"])
+    for name in want:
+        assert said[name].startswith(
+            f"{name}={getattr(base, name)!r} in the program's arch")
 
 
 def test_head_dim_left_out_is_hidden_over_heads():
@@ -79,19 +121,19 @@ def test_head_dim_left_out_is_hidden_over_heads():
     assert flops._head_dims(cfg) == (arch.hd, arch.hd)
 
 
-def test_moonlight_stops_at_the_mapping():
-    """Moonlight's published keys stop at the mapping, which names every
-    expert, routing and latent-attention key, the norm's epsilon (the
-    program's is 1e-6) and the arch's experts that no key sets, and no
+def test_moonlight_stops_at_the_mapping(base_keys):
+    """Moonlight's published keys stop at the base mapping, which names
+    every expert, routing and latent-attention key, the norm's epsilon
+    (the base runs 1e-6) and the arch's fields that no key sets, and no
     key it takes or lists as inert."""
-    said = refusals(MOONLIGHT)
+    said = refusals(MOONLIGHT, base_keys)
     assert set(said) == {
         "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
         "v_head_dim", "first_k_dense_replace", "moe_intermediate_size",
         "moe_layer_freq", "n_routed_experts", "n_shared_experts",
         "num_experts_per_tok", "n_group", "topk_group", "norm_topk_prob",
         "routed_scaling_factor", "scoring_func", "topk_method",
-        "rms_norm_eps", "family", "n_experts", "top_k"}
+        "rms_norm_eps"} | left_to_the_arch(MOONLIGHT, base_keys)
     assert said["rms_norm_eps"] == (
         "rms_norm_eps=1e-05 (the program runs 1e-06)")
 
@@ -113,6 +155,35 @@ def test_a_later_mapping_file_takes_its_keys(tmp_path):
         {"keys": {"num_experts_per_tok": {"top_k": "float"}}}))
     with pytest.raises(ValueError, match="num_experts_per_tok"):
         load_keys(tmp_path)
+
+
+def test_a_later_mapping_file_takes_a_key_the_base_runs(tmp_path,
+                                                      monkeypatch):
+    """A file that maps ``rms_norm_eps`` lifts the base's guard on it:
+    1e-05 then sets the field, and 1e-06, which qwen states, its
+    default.  The field is a stand-in's, patched in for the program's
+    arch; without the file the refusal is the base's, word for word."""
+    import repro.configs
+    from repro.models.lm import ArchConfig
+
+    stand_in = dataclasses.make_dataclass(
+        "StandIn", [("norm_eps", float, dataclasses.field(default=1e-6))],
+        bases=(ArchConfig,), frozen=True)
+    real = repro.configs.get_config
+    monkeypatch.setattr(repro.configs, "get_config", lambda name: stand_in(
+        **{f.name: getattr(real(name), f.name)
+           for f in dataclasses.fields(ArchConfig)}))
+    shutil.copy(KEYS_DIR / "base.json", tmp_path)
+    eps = dict(config("qwen3-4b"), rms_norm_eps=1e-5)
+    assert refusals(eps, load_keys(tmp_path)) == {
+        "rms_norm_eps": "rms_norm_eps=1e-05 (the program runs 1e-06)"}
+    (tmp_path / "norm.json").write_text(json.dumps(
+        {"keys": {"rms_norm_eps": {"norm_eps": "float"}}}))
+    keys = load_keys(tmp_path)
+    assert program_arch(eps, keys).norm_eps == 1e-5
+    as_run = program_arch(config("qwen3-4b"), keys)
+    assert as_run.norm_eps == 1e-6
+    assert as_run == program_arch(config("qwen3-4b"), load_keys())
 
 
 def test_weights_fill_an_expert_layout():

@@ -149,3 +149,11 @@ def test_traced_run_serves_the_whole_window(monkeypatch):
         traffic.tokens_processed(p, n) for p, n in sizes)
     assert ctx.window_s < ctx.counts["served_s"]
     assert r["device"]["window_s"] == ctx.window_s
+    # every counter of the gateway across the span, the step program's
+    # scope map and the parameters' PTC leaves reach the readers
+    assert {"step_count", "busy_steps", "slot_steps", "kv_live_positions",
+            "tokens_out"} <= set(ctx.counts)
+    assert 0 < ctx.counts["busy_steps"] <= ctx.counts["step_count"]
+    assert ctx.counts["kv_live_positions"] > 0
+    assert ctx.ptc_leaves == {"wq", "wk", "wv", "wo", "gate", "up", "down"}
+    assert any("s0.attn" in op for op in ctx.scopes.values())
