@@ -175,6 +175,36 @@ def attention(p: Params, cfg: AttnCfg, lin: PTCLinearCfg, x, positions,
 # -- decode (serve path) -----------------------------------------------------
 
 
+def _grouped_decode(q, k, v, ok, cfg: AttnCfg, new=None):
+    """One query row per head against K/V read by KV-head group.
+
+    q: (B, 1, H, Dh); k/v: (B, S, Hkv, Dh), read in their own layout with
+    no per-head repeat: q is taken as (B, Hkv, rep, Dh), so head h is
+    member ``h % rep`` of group ``h // rep`` — ``jnp.repeat(k, rep,
+    axis=2)``'s order.  ok: (B or 1, S) bool, the keys each row may
+    attend.  ``new``: optional (k_new, v_new), each (B, 1, Hkv, Dh) in
+    k's dtype, taken as one more key after column S that every row
+    attends.  Returns (B, 1, H·Dh) in q's dtype."""
+    b, _, h, dh = q.shape
+    s, g = k.shape[1], k.shape[2]
+    qg = q.reshape(b, g, h // g, dh)
+    logits = jnp.einsum("bgrd,bsgd->bgrs", qg, k)
+    if new is not None:
+        col = jnp.einsum("bgrd,bgd->bgr", qg, new[0][:, 0])
+        logits = jnp.concatenate([logits, col[..., None]], axis=-1)
+        ok = jnp.pad(ok, ((0, 0), (0, 1)), constant_values=True)
+    logits = logits.astype(jnp.float32) * (dh ** -0.5)
+    logits = softcap(logits, cfg.attn_softcap)
+    logits = jnp.where(ok[:, None, None, :], logits, NEG_INF)
+    w = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    o = jnp.einsum("bgrs,bsgd->bgrd", w[..., :s], v,
+                   preferred_element_type=jnp.float32)
+    if new is not None:
+        o = o + jnp.einsum("bgr,bgd->bgrd", w[..., s], new[1][:, 0],
+                           preferred_element_type=jnp.float32)
+    return o.astype(q.dtype).reshape(b, 1, h * dh)
+
+
 def init_kv_cache(batch: int, max_len: int, cfg: AttnCfg, dtype=jnp.bfloat16):
     shape = (batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -193,21 +223,11 @@ def decode_attention(p: Params, cfg: AttnCfg, lin: PTCLinearCfg, x, cache,
         cache["k"], k_new.astype(cache["k"].dtype), cache_len, axis=1)
     v = jax.lax.dynamic_update_slice_in_dim(
         cache["v"], v_new.astype(cache["v"].dtype), cache_len, axis=1)
-    sk = k.shape[1]
-    rep = cfg.n_heads // cfg.n_kv_heads
-    kr = jnp.repeat(k, rep, axis=2)
-    vr = jnp.repeat(v, rep, axis=2)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(jnp.float32)
-    logits = logits * (cfg.head_dim ** -0.5)
-    logits = softcap(logits, cfg.attn_softcap)
-    ki = jnp.arange(sk)[None, None, None, :]
+    ki = jnp.arange(k.shape[1])[None, :]
     ok = ki <= cache_len
     if cfg.window is not None:
         ok = ok & (ki > cache_len - cfg.window)
-    logits = jnp.where(ok, logits, NEG_INF)
-    w = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    o = jnp.einsum("bhqk,bkhd->bqhd", w, vr)
-    o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)
+    o = _grouped_decode(q, k, v, ok, cfg)
     out = apply_ptc_linear(p["wo"], o, lin, d_out=cfg.d_model, name="wo")
     return out, {"k": k, "v": v}
 
@@ -218,39 +238,31 @@ def decode_attention_paged(p: Params, cfg: AttnCfg, lin: PTCLinearCfg, x,
     *per-sequence* cache lengths (the continuous-batching gateway path).
 
     x: (B, 1, d); k_view/v_view: (B, S_max, Hkv, Dh) contiguous views
-    gathered from the page pool (position ``lens[b]`` is within slot
-    b's reservation); lens: (B,) int32 valid lengths — heterogeneous
-    across the batch, unlike :func:`decode_attention`'s shared scalar.
+    gathered from the page pool; lens: (B,) int32 valid lengths —
+    heterogeneous across the batch, unlike :func:`decode_attention`'s
+    shared scalar.  Slot b attends view rows ``< lens[b]`` (inside the
+    window, if any) and its own new row, which sits at position
+    ``lens[b]``.
+
+    The views are read once, in place, in their own layout: GQA is read
+    by KV-head group (:func:`_grouped_decode`), with no per-head copy of
+    K or V, and nothing is spliced into them — the new row is scored as
+    one more key column after the view's S_max.
 
     Returns ``(out, k_new, v_new)``: the caller persists the new
     (B, 1, Hkv, Dh) rows into the page pool (``kernels.paged_scatter``);
     the assembled views are step-scratch and never written back.
     """
-    b = x.shape[0]
     lens = lens.astype(jnp.int32)
-    positions = lens[:, None]
-    q, k_new, v_new = _project_qkv(p, cfg, lin, x, positions)
-    # splice each slot's new row in at its own write position
-    ins = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
-        c, n, i, axis=0))
-    k = ins(k_view, k_new.astype(k_view.dtype), lens)
-    v = ins(v_view, v_new.astype(v_view.dtype), lens)
-    sk = k.shape[1]
-    rep = cfg.n_heads // cfg.n_kv_heads
-    kr = jnp.repeat(k, rep, axis=2)
-    vr = jnp.repeat(v, rep, axis=2)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(jnp.float32)
-    logits = logits * (cfg.head_dim ** -0.5)
-    logits = softcap(logits, cfg.attn_softcap)
-    ki = jnp.arange(sk)[None, None, None, :]
-    ln = lens[:, None, None, None]
-    ok = ki <= ln
+    q, k_new, v_new = _project_qkv(p, cfg, lin, x, lens[:, None])
+    ki = jnp.arange(k_view.shape[1])[None, :]
+    ln = lens[:, None]
+    ok = ki < ln
     if cfg.window is not None:
         ok = ok & (ki > ln - cfg.window)
-    logits = jnp.where(ok, logits, NEG_INF)
-    w = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    o = jnp.einsum("bhqk,bkhd->bqhd", w, vr)
-    o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)
+    o = _grouped_decode(q, k_view, v_view, ok, cfg,
+                        new=(k_new.astype(k_view.dtype),
+                             v_new.astype(v_view.dtype)))
     out = apply_ptc_linear(p["wo"], o, lin, d_out=cfg.d_model, name="wo")
     return out, k_new, v_new
 
