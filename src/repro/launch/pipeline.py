@@ -27,8 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..models.lm import (ArchConfig, period_plan, _sublayer_fwd, _apply_norm,
-                         embed, softcap, cross_entropy)
+from ..models.lm import (ArchConfig, period_plan, _embed, _head,
+                         _run_periods, _train_mixer, cross_entropy)
 
 Params = dict[str, Any]
 
@@ -63,13 +63,8 @@ def build_pp_loss(cfg: ArchConfig, n_stages: int, n_micro: int):
 
     def stage_stack(stack_local, x, positions):
         """Run this stage's local periods (scan over n_periods/S)."""
-        def body(carry, layer_params):
-            h, _ = _sublayer_fwd(cfg, plan[0], layer_params, carry,
-                                 positions)
-            return h, None
-        if cfg.remat:
-            body = jax.checkpoint(body)
-        x, _ = jax.lax.scan(body, x, stack_local)
+        x, _, _ = _run_periods(cfg, plan, {"pos0": stack_local}, x,
+                               _train_mixer(cfg, positions), train=True)
         return x
 
     def local_fn(params, tokens, labels, stage_ids):
@@ -85,16 +80,11 @@ def build_pp_loss(cfg: ArchConfig, n_stages: int, n_micro: int):
 
         def embed_micro(m):
             toks = jax.lax.dynamic_slice_in_dim(tokens, m * mb, mb, 0)
-            x = embed(params["embed"], toks)
-            return x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+            return _embed(cfg, params, toks)
 
         def tail_loss(x, m):
-            x = _apply_norm(cfg, params["final_norm"], x)
-            logits = x @ (params["embed"]["e"].T if cfg.tie_embed
-                          else params["unembed"]["w"].T)
-            logits = softcap(logits, cfg.final_softcap)
             lbl = jax.lax.dynamic_slice_in_dim(labels, m * mb, mb, 0)
-            return cross_entropy(logits, lbl)
+            return cross_entropy(_head(cfg, params, x), lbl)
 
         d = cfg.d_model
         carry_in = jnp.zeros((mb, s, d), params["embed"]["e"].dtype)

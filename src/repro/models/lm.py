@@ -283,67 +283,113 @@ def inject_masks(params: Params, key: jax.Array, scfg: SparsityConfig,
 
 
 # ---------------------------------------------------------------------------
-# forward
+# the decoder body: every path supplies only its token mixer
 # ---------------------------------------------------------------------------
 
 
-def _sublayer_fwd(cfg: ArchConfig, plan: SubLayerPlan, p: Params, x, positions,
-                  cross_kv=None):
-    """One sub-layer (train/prefill path).  Returns (x, aux_loss)."""
+def _embed(cfg: ArchConfig, params: Params, tokens: jax.Array) -> jax.Array:
+    """Token embedding, scaled by sqrt(d_model) (ssm stacks: unscaled)."""
+    x = embed(params["embed"], tokens)
+    if cfg.family != "ssm":
+        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    return x
+
+
+def _train_mixer(cfg: ArchConfig, positions):
+    """The full-sequence mixer: attention or the selective scan."""
+    def mix(sub, p, h, state):
+        if sub.kind == "attn":
+            return attention(p, cfg.attn_cfg(sub.window, sub.causal),
+                             cfg.ptc, h, positions,
+                             chunk=cfg.attn_chunk), state
+        return mamba(p, cfg.ssm_cfg(), cfg.ptc, h), state
+    return mix
+
+
+def _sublayer(cfg: ArchConfig, i: int, sub: SubLayerPlan, p: Params, x,
+              mixer, state, cross_kv):
+    """Sub-layer ``i``: ln1 → mixer → pn1 → residual → (cross) → mlp/moe
+    → pn2 → residual.  ``mixer(sub, p[sub.kind], h, state)`` returns
+    (h, new_state) over this position's state (dense cache, paged views,
+    SSM state or nothing).  Returns (x, new_state, MoE aux loss).
+
+    The ``s{i}.<module>`` scopes name PTC layers for the hardware-in-the-
+    loop executor (a stable layer id) and for profiles; under jit they
+    only run at trace time and the hook stays inert."""
     aux = jnp.zeros((), jnp.float32)
     h = _apply_norm(cfg, p["ln1"], x)
-    if plan.kind == "attn":
-        h = attention(p["attn"], cfg.attn_cfg(plan.window, plan.causal),
-                      cfg.ptc, h, positions, chunk=cfg.attn_chunk)
-    else:
-        h = mamba(p["mamba"], cfg.ssm_cfg(), cfg.ptc, h)
+    with ptc_scope(f"s{i}.{sub.kind}"):
+        h, state = mixer(sub, p[sub.kind], h, state)
     if cfg.post_norm:
         h = _apply_norm(cfg, p["pn1"], h)
     x = x + h
-    if plan.cross:
+    if sub.cross:
         h = _apply_norm(cfg, p["lnx"], x)
-        h = attention(p["cross"], cfg.attn_cfg(causal=False), cfg.ptc, h,
-                      None, kv_x=cross_kv)
+        with ptc_scope(f"s{i}.cross"):
+            h = attention(p["cross"], cfg.attn_cfg(causal=False), cfg.ptc, h,
+                          None, kv_x=cross_kv)
         x = x + h
-    if plan.ffn != "none":
+    if sub.ffn != "none":
         h = _apply_norm(cfg, p["ln2"], x)
-        if plan.ffn == "moe":
+        if sub.ffn == "moe":
             h, a = moe(p["moe"], cfg.moe_cfg(), cfg.ptc, h)
             aux = aux + a
         else:
-            h = mlp(p["mlp"], cfg.ffn_cfg(), cfg.ptc, h)
+            with ptc_scope(f"s{i}.mlp"):
+                h = mlp(p["mlp"], cfg.ffn_cfg(), cfg.ptc, h)
         if cfg.post_norm:
             h = _apply_norm(cfg, p["pn2"], h)
         x = x + h
-    return x, aux
+    return x, state, aux
 
 
-def _run_stack(cfg: ArchConfig, plan, stacked: list[Params], x, positions,
-               cross_kv=None):
-    """Scan the period stack.  ``stacked[i]`` has leading period axis."""
-    def body(carry, layer_params):
+def _run_periods(cfg: ArchConfig, plan, params: Params, x, mixer,
+                 state: Params | None = None, cross_kv=None,
+                 train: bool = False):
+    """Run ``plan`` over the period stacks ``params["pos{i}"]`` by
+    ``lax.scan``, or unrolled under ``p{period}`` scopes.  ``state``,
+    keyed and stacked like them, is sliced per period into the mixer and
+    its new value stacked back.  ``train`` sums the MoE aux loss and
+    applies the remat policy.  Returns (x, aux or None, new_state)."""
+    stack = {f"pos{i}": params[f"pos{i}"] for i in range(len(plan))}
+    state = {} if state is None else state
+
+    def body(carry, per):
         x, aux = carry
+        layer_params, layer_state = per
+        new = {}
         for i, sub in enumerate(plan):
-            x, a = _sublayer_fwd(cfg, sub, layer_params[i], x, positions,
-                                 cross_kv)
-            aux = aux + a
-        return (x, aux), None
+            x, new[f"pos{i}"], a = _sublayer(
+                cfg, i, sub, layer_params[f"pos{i}"], x, mixer,
+                layer_state.get(f"pos{i}"), cross_kv)
+            if train:
+                aux = aux + a
+        return (x, aux), new
 
-    if cfg.remat and cfg.remat_policy != "none":
+    if train and cfg.remat and cfg.remat_policy != "none":
         policy = None
         if cfg.remat_policy == "dots":
             policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         body = jax.checkpoint(body, policy=policy)
+    carry = (x, jnp.zeros((), jnp.float32) if train else None)
     if cfg.unroll:
-        n_periods = jax.tree.leaves(stacked[0])[0].shape[0]
-        carry = (x, jnp.zeros((), jnp.float32))
-        for pi in range(n_periods):
-            layer = [jax.tree.map(lambda a: a[pi], st) for st in stacked]
-            carry, _ = body(carry, layer)
-        return carry
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               stacked)
-    return x, aux
+        outs = []
+        for pi in range(jax.tree.leaves(stack)[0].shape[0]):
+            per = jax.tree.map(lambda a: a[pi], (stack, state))
+            with ptc_scope(f"p{pi}"):
+                carry, new = body(carry, per)
+            outs.append(new)
+        new_state = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    else:
+        carry, new_state = jax.lax.scan(body, carry, (stack, state))
+    return carry[0], carry[1], new_state
+
+
+def _head(cfg: ArchConfig, params: Params, x) -> jax.Array:
+    """Final norm, the tied or untied unembedding, and the logit softcap."""
+    x = _apply_norm(cfg, params["final_norm"], x)
+    w = params["embed"]["e"] if cfg.tie_embed else params["unembed"]["w"]
+    return softcap(x @ w.T, cfg.final_softcap)
 
 
 def forward(params: Params, cfg: ArchConfig, batch: dict[str, jax.Array],
@@ -352,34 +398,28 @@ def forward(params: Params, cfg: ArchConfig, batch: dict[str, jax.Array],
     tokens = batch["tokens"]
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    x = embed(params["embed"], tokens)
-    if cfg.family != "ssm":
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = _embed(cfg, params, tokens)
 
     cross_kv = None
     if cfg.family == "encdec":
         enc = batch["frames"].astype(x.dtype)       # stubbed audio frontend
         enc_pos = jnp.broadcast_to(jnp.arange(enc.shape[1])[None],
                                    (b, enc.shape[1]))
-        enc_out, _ = _run_stack(
+        enc_out, _, _ = _run_periods(
             cfg, [SubLayerPlan("attn", "mlp", causal=False)],
-            [params["enc"]], enc, enc_pos)
+            {"pos0": params["enc"]}, enc, _train_mixer(cfg, enc_pos),
+            train=True)
         cross_kv = _apply_norm(cfg, params["enc_norm"], enc_out)
     if cfg.family == "vlm":
         cross_kv = batch["img"].astype(x.dtype)     # stubbed vision tower
 
     plan, _ = period_plan(cfg)
-    stacked = [params[f"pos{i}"] for i in range(len(plan))]
-    x, aux = _run_stack(cfg, plan, stacked, x, positions, cross_kv)
-    x = _apply_norm(cfg, params["final_norm"], x)
-    if cfg.tie_embed:
-        logits = x @ params["embed"]["e"].T
-    else:
-        logits = x @ params["unembed"]["w"].T
+    x, aux, _ = _run_periods(cfg, plan, params, x,
+                             _train_mixer(cfg, positions),
+                             cross_kv=cross_kv, train=True)
     # keep the (B, S, vocab) logits vocab-sharded — replicated logits are
     # ~20 GB/device at 152k vocab (measured); CE reduces over the shard
-    logits = maybe_constraint(logits, "dp", None, "model")
-    logits = softcap(logits, cfg.final_softcap)
+    logits = maybe_constraint(_head(cfg, params, x), "dp", None, "model")
     return logits, aux
 
 
@@ -473,260 +513,102 @@ def build_serve_step(cfg: ArchConfig):
     """Returns serve_step(params, cache, batch) → (logits, new_cache).
 
     ``batch``: {"token": (B,1) int32, "cache_len": () int32,
-    ["img"/"frames" for vlm/encdec]}.  One new token against a KV cache
+    ["img"/"enc_out" for vlm/encdec]}.  One new token against a KV cache
     of length ``cache_len`` (the decode_* / long_* dry-run shapes)."""
-    plan, n_periods = period_plan(cfg)
+    plan, _ = period_plan(cfg)
 
     def serve_step(params, cache, batch):
-        tok = batch["token"]
-        b = tok.shape[0]
         cache_len = batch["cache_len"]
-        x = embed(params["embed"], tok)
-        if cfg.family != "ssm":
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        x = _embed(cfg, params, batch["token"])
         cross_kv = None
         if cfg.family == "vlm":
             cross_kv = batch["img"].astype(x.dtype)
         if cfg.family == "encdec":
             cross_kv = batch["enc_out"].astype(x.dtype)
 
-        def body(x, per):
-            # PTC layers are name-scoped (``p{period}.s{sublayer}.<module>``)
-            # so the hardware-in-the-loop executor (models.layers.
-            # ptc_execution) can key tenant placement on a stable layer id;
-            # under lax.scan the scopes only run at trace time and the hook
-            # stays inert (tracer guard), so naming costs nothing there.
-            layer_params, layer_cache = per
-            new_cache = {}
-            for i, sub in enumerate(plan):
-                p = layer_params[f"pos{i}"]
-                c = layer_cache[f"pos{i}"]
-                h = _apply_norm(cfg, p["ln1"], x)
-                if sub.kind == "attn":
-                    with ptc_scope(f"s{i}.attn"):
-                        h, c = decode_attention(p["attn"],
-                                                cfg.attn_cfg(sub.window),
-                                                cfg.ptc, h, c, cache_len)
-                else:
-                    with ptc_scope(f"s{i}.mamba"):
-                        h, c = mamba_decode(p["mamba"], cfg.ssm_cfg(),
-                                            cfg.ptc, h, c)
-                if cfg.post_norm:
-                    h = _apply_norm(cfg, p["pn1"], h)
-                x = x + h
-                if sub.cross:
-                    h = _apply_norm(cfg, p["lnx"], x)
-                    with ptc_scope(f"s{i}.cross"):
-                        h = attention(p["cross"], cfg.attn_cfg(causal=False),
-                                      cfg.ptc, h, None, kv_x=cross_kv)
-                    x = x + h
-                if sub.ffn != "none":
-                    h = _apply_norm(cfg, p["ln2"], x)
-                    if sub.ffn == "moe":
-                        h, _ = moe(p["moe"], cfg.moe_cfg(), cfg.ptc, h)
-                    else:
-                        with ptc_scope(f"s{i}.mlp"):
-                            h = mlp(p["mlp"], cfg.ffn_cfg(), cfg.ptc, h)
-                    if cfg.post_norm:
-                        h = _apply_norm(cfg, p["pn2"], h)
-                    x = x + h
-                new_cache[f"pos{i}"] = c
-            return x, new_cache
+        def mixer(sub, p, h, c):
+            if sub.kind == "attn":
+                return decode_attention(p, cfg.attn_cfg(sub.window), cfg.ptc,
+                                        h, c, cache_len)
+            return mamba_decode(p, cfg.ssm_cfg(), cfg.ptc, h, c)
 
-        layer_stack = {f"pos{i}": params[f"pos{i}"] for i in range(len(plan))}
-        if cfg.unroll:
-            outs = []
-            for pi in range(n_periods):
-                lp = jax.tree.map(lambda a: a[pi], layer_stack)
-                lc = jax.tree.map(lambda a: a[pi], cache)
-                with ptc_scope(f"p{pi}"):
-                    x, c = body(x, (lp, lc))
-                outs.append(c)
-            new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        else:
-            x, new_cache = jax.lax.scan(body, x, (layer_stack, cache))
-        x = _apply_norm(cfg, params["final_norm"], x)
-        if cfg.tie_embed:
-            logits = x @ params["embed"]["e"].T
-        else:
-            logits = x @ params["unembed"]["w"].T
-        return softcap(logits, cfg.final_softcap)[:, 0], new_cache
+        x, _, new_cache = _run_periods(cfg, plan, params, x, mixer, cache,
+                                       cross_kv)
+        return _head(cfg, params, x)[:, 0], new_cache
 
     return serve_step
 
 
-def build_gateway_step(cfg: ArchConfig):
-    """Returns gateway_step(params, views, batch) → (logits, new_kv):
-    the continuous-batching decode step over *page-assembled* KV views
-    with per-sequence cache lengths (``repro.serving.engine``).
-
-    ``batch``: {"token": (B, 1) int32, "lens": (B,) int32} — B is the
-    gateway's slot count, each slot at its own decode position.
-    ``views`` mirrors :func:`init_decode_cache`'s tree: per sub-layer
-    position either ``{"k","v"}`` views (n_periods, B, S_max, Hkv, Dh)
-    gathered from the page pool, or an SSM state.  Unlike the dense
-    serve step the views are step-scratch: the returned ``new_kv``
-    holds only each attention layer's NEW (n_periods, B, 1, Hkv, Dh)
-    rows (the engine scatters them into the pool) plus full replacement
-    SSM states.
-
-    PTC scope names are identical to :func:`build_serve_step`'s
-    (``p{period}.s{sub}.attn.wq`` …), so a hardware-in-the-loop
-    deployment recorded off the solo serve path routes the gateway's
-    coalesced frames onto the same tenants."""
-    plan, n_periods = period_plan(cfg)
+def _gateway_plan(cfg: ArchConfig, chunked: bool) -> list[SubLayerPlan]:
+    """The period plan of an arch the paged gateway steps serve."""
+    plan, _ = period_plan(cfg)
     if cfg.family in ("vlm", "encdec"):
         raise ValueError(
             f"gateway decode does not support {cfg.family} archs "
             f"(per-request cross-attention streams are not paged yet)")
     if cfg.n_experts > 0:
         raise ValueError("gateway decode does not support MoE archs yet")
+    if chunked and any(sub.kind != "attn" for sub in plan):
+        raise ValueError(
+            "chunked prefill supports attention-only archs; ssm/hybrid "
+            "token recurrences are sequential — use prefill_chunk=1")
+    return plan
+
+
+def _paged_step(cfg: ArchConfig, plan, attend, params, views, batch):
+    """The gateway steps' body: ``attend`` (one-token or chunked paged
+    attention) over the step's KV views returns the new rows for the
+    engine to scatter.  Returns (logits at every column, new_kv)."""
+    def mixer(sub, p, h, c):
+        if sub.kind != "attn":
+            return mamba_decode(p, cfg.ssm_cfg(), cfg.ptc, h, c)
+        h, k_new, v_new = attend(p, cfg.attn_cfg(sub.window), cfg.ptc, h,
+                                 c["k"], c["v"], batch["lens"])
+        return h, {"k": k_new, "v": v_new}
+
+    x, _, new_kv = _run_periods(cfg, plan, params,
+                                _embed(cfg, params, batch["token"]), mixer,
+                                views)
+    return _head(cfg, params, x), new_kv
+
+
+def build_gateway_step(cfg: ArchConfig):
+    """Returns gateway_step(params, views, batch) → (logits, new_kv): the
+    continuous-batching decode step (``repro.serving.engine``).
+
+    ``batch``: {"token": (B, 1), "lens": (B,)} int32, one position per
+    slot.  ``views`` is :func:`init_decode_cache`'s tree with (n_periods,
+    B, S_max, Hkv, Dh) K/V views gathered from the page pool; ``new_kv``
+    holds the NEW (n_periods, B, 1, Hkv, Dh) rows the engine scatters,
+    and new SSM states.  The body, and so each PTC scope name, is the
+    solo serve step's, on which hardware deployments are recorded."""
+    plan = _gateway_plan(cfg, chunked=False)
 
     def gateway_step(params, views, batch):
-        tok = batch["token"]
-        lens = batch["lens"]
-        x = embed(params["embed"], tok)
-        if cfg.family != "ssm":
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-
-        def body(x, per):
-            layer_params, layer_views = per
-            new = {}
-            for i, sub in enumerate(plan):
-                p = layer_params[f"pos{i}"]
-                c = layer_views[f"pos{i}"]
-                h = _apply_norm(cfg, p["ln1"], x)
-                if sub.kind == "attn":
-                    with ptc_scope(f"s{i}.attn"):
-                        h, k_new, v_new = decode_attention_paged(
-                            p["attn"], cfg.attn_cfg(sub.window), cfg.ptc,
-                            h, c["k"], c["v"], lens)
-                    new[f"pos{i}"] = {"k": k_new, "v": v_new}
-                else:
-                    with ptc_scope(f"s{i}.mamba"):
-                        h, st = mamba_decode(p["mamba"], cfg.ssm_cfg(),
-                                             cfg.ptc, h, c)
-                    new[f"pos{i}"] = st
-                if cfg.post_norm:
-                    h = _apply_norm(cfg, p["pn1"], h)
-                x = x + h
-                if sub.ffn != "none":
-                    h = _apply_norm(cfg, p["ln2"], x)
-                    with ptc_scope(f"s{i}.mlp"):
-                        h = mlp(p["mlp"], cfg.ffn_cfg(), cfg.ptc, h)
-                    if cfg.post_norm:
-                        h = _apply_norm(cfg, p["pn2"], h)
-                    x = x + h
-            return x, new
-
-        layer_stack = {f"pos{i}": params[f"pos{i}"] for i in range(len(plan))}
-        if cfg.unroll:
-            outs = []
-            for pi in range(n_periods):
-                lp = jax.tree.map(lambda a: a[pi], layer_stack)
-                lv = jax.tree.map(lambda a: a[pi], views)
-                with ptc_scope(f"p{pi}"):
-                    x, nk = body(x, (lp, lv))
-                outs.append(nk)
-            new_kv = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        else:
-            x, new_kv = jax.lax.scan(body, x, (layer_stack, views))
-        x = _apply_norm(cfg, params["final_norm"], x)
-        if cfg.tie_embed:
-            logits = x @ params["embed"]["e"].T
-        else:
-            logits = x @ params["unembed"]["w"].T
-        return softcap(logits, cfg.final_softcap)[:, 0], new_kv
+        logits, new_kv = _paged_step(cfg, plan, decode_attention_paged,
+                                     params, views, batch)
+        return logits[:, 0], new_kv
 
     return gateway_step
 
 
 def build_gateway_prefill_step(cfg: ArchConfig):
-    """Returns prefill_step(params, views, batch) → (logits, new_kv):
-    the chunked-prefill gateway step — every slot advances up to C
-    tokens per call instead of one.
+    """Returns prefill_step(params, views, batch) → (logits, new_kv): the
+    chunked-prefill gateway step, every slot advancing up to C tokens.
 
-    ``batch``: {"token": (B, C) int32, "lens": (B,) int32,
-    "n_valid": (B,) int32} — slot b's next ``n_valid[b]`` tokens sit in
-    columns 0..n_valid-1 at absolute positions ``lens[b] + c`` (decode
-    slots ride along with n_valid == 1; padding columns are arbitrary
-    and masked).  ``views`` is :func:`build_gateway_step`'s tree; the
-    returned ``new_kv`` holds (n_periods, B, C, Hkv, Dh) rows per
-    attention position, of which the engine scatters the first
-    ``n_valid[b]`` per slot.  Logits are taken at column
-    ``n_valid[b]-1`` — the prediction after the slot's last real token
-    — so the return shape matches the one-token step: (B, vocab).
-
-    PTC scope names are IDENTICAL to :func:`build_gateway_step`
-    (``p{period}.s{sub}.attn.wq`` …): a hardware deployment recorded
-    off the solo serve path routes the wide (B·C-column) prefill frames
-    onto the same tenants untouched.  The Pallas kernel derives its KV
-    block from the shapes.
-
-    Attention-only: ssm/hybrid recurrences are inherently sequential in
-    tokens, and vlm/encdec/MoE are not paged at all — those archs keep
-    the one-token path."""
-    plan, n_periods = period_plan(cfg)
-    if cfg.family in ("vlm", "encdec"):
-        raise ValueError(
-            f"gateway decode does not support {cfg.family} archs "
-            f"(per-request cross-attention streams are not paged yet)")
-    if cfg.n_experts > 0:
-        raise ValueError("gateway decode does not support MoE archs yet")
-    if any(sub.kind != "attn" for sub in plan):
-        raise ValueError(
-            "chunked prefill supports attention-only archs; ssm/hybrid "
-            "token recurrences are sequential — use prefill_chunk=1")
+    ``batch``: {"token": (B, C), "lens": (B,), "n_valid": (B,)} int32:
+    slot b's next ``n_valid[b]`` tokens sit in columns 0..n_valid-1 at
+    positions ``lens[b] + c`` (decode slots have n_valid == 1; padding
+    columns are masked).  ``new_kv`` holds (n_periods, B, C, Hkv, Dh)
+    rows, of which the engine scatters the first ``n_valid[b]``.  Logits
+    are column ``n_valid[b]-1``'s: (B, vocab), as the one-token step's.
+    Attention-only: ssm/hybrid recurrences are sequential in tokens."""
+    plan = _gateway_plan(cfg, chunked=True)
 
     def prefill_step(params, views, batch):
-        tok = batch["token"]
-        lens = batch["lens"]
         n_valid = batch["n_valid"].astype(jnp.int32)
-        x = embed(params["embed"], tok)
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-
-        def body(x, per):
-            layer_params, layer_views = per
-            new = {}
-            for i, sub in enumerate(plan):
-                p = layer_params[f"pos{i}"]
-                c = layer_views[f"pos{i}"]
-                h = _apply_norm(cfg, p["ln1"], x)
-                with ptc_scope(f"s{i}.attn"):
-                    h, k_new, v_new = decode_attention_paged_chunked(
-                        p["attn"], cfg.attn_cfg(sub.window), cfg.ptc,
-                        h, c["k"], c["v"], lens)
-                new[f"pos{i}"] = {"k": k_new, "v": v_new}
-                if cfg.post_norm:
-                    h = _apply_norm(cfg, p["pn1"], h)
-                x = x + h
-                if sub.ffn != "none":
-                    h = _apply_norm(cfg, p["ln2"], x)
-                    with ptc_scope(f"s{i}.mlp"):
-                        h = mlp(p["mlp"], cfg.ffn_cfg(), cfg.ptc, h)
-                    if cfg.post_norm:
-                        h = _apply_norm(cfg, p["pn2"], h)
-                    x = x + h
-            return x, new
-
-        layer_stack = {f"pos{i}": params[f"pos{i}"] for i in range(len(plan))}
-        if cfg.unroll:
-            outs = []
-            for pi in range(n_periods):
-                lp = jax.tree.map(lambda a: a[pi], layer_stack)
-                lv = jax.tree.map(lambda a: a[pi], views)
-                with ptc_scope(f"p{pi}"):
-                    x, nk = body(x, (lp, lv))
-                outs.append(nk)
-            new_kv = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        else:
-            x, new_kv = jax.lax.scan(body, x, (layer_stack, views))
-        x = _apply_norm(cfg, params["final_norm"], x)
-        if cfg.tie_embed:
-            logits = x @ params["embed"]["e"].T
-        else:
-            logits = x @ params["unembed"]["w"].T
-        logits = softcap(logits, cfg.final_softcap)      # (B, C, V)
+        logits, new_kv = _paged_step(                    # (B, C, V)
+            cfg, plan, decode_attention_paged_chunked, params, views, batch)
         last = jnp.take_along_axis(logits, (n_valid - 1)[:, None, None],
                                    axis=1)
         return last[:, 0], new_kv

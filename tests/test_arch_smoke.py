@@ -68,19 +68,24 @@ def test_serve_step_smoke(name):
     assert jax.tree.structure(new_cache) == jax.tree.structure(cache)
 
 
-def test_decode_matches_prefill_logits():
+# whisper-base's serve step takes the encoder output from outside
+@pytest.mark.parametrize("name", [n for n in ARCH_NAMES
+                                  if n != "whisper-base"])
+def test_decode_matches_prefill_logits(name):
     """Teacher-forced decode reproduces the prefill logits (same params,
-    same tokens) — the serve path is consistent with the train path."""
-    cfg = smoke_config("qwen3-4b")
+    same tokens) — the serve path and the train path run one body."""
+    cfg = smoke_config(name)
     key = jax.random.PRNGKey(0)
     params = init_model(key, cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab)
-    logits_all, _ = forward(params, cfg, {"tokens": toks})
+    extra = {k: v for k, v in _batch(cfg, jax.random.PRNGKey(2)).items()
+             if k == "img"}
+    logits_all, _ = forward(params, cfg, {"tokens": toks, **extra})
     cache = init_decode_cache(cfg, B, S)
     serve = jax.jit(build_serve_step(cfg))
     for i in range(4):
         batch = {"token": toks[:, i: i + 1],
-                 "cache_len": jnp.asarray(i, jnp.int32)}
+                 "cache_len": jnp.asarray(i, jnp.int32), **extra}
         logits_i, cache = serve(params, cache, batch)
         np.testing.assert_allclose(np.asarray(logits_i),
                                    np.asarray(logits_all[:, i]),

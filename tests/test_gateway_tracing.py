@@ -12,9 +12,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import ARCH_NAMES, smoke_config
 from repro.models.layers import PTCLinearCfg
-from repro.models.lm import (ArchConfig, build_serve_step, init_decode_cache,
-                             init_model)
+from repro.models.lm import (ArchConfig, build_gateway_prefill_step,
+                             build_gateway_step, build_serve_step,
+                             init_decode_cache, init_model, period_plan)
 from repro.runtime.hw_serve import record_ptc_layers
 from repro.serving import GatewayConfig, PageConfig, Request, ServingGateway
 
@@ -167,3 +169,60 @@ def test_ptc_layer_names_unchanged():
                      for mod, leaves in (("attn", LEAVES[:4]),
                                          ("mlp", LEAVES[4:]))
                      for leaf in leaves]
+
+
+def _scope_sequence(text: str) -> list[tuple[str | None, str]]:
+    """(period, sub-layer) scope pairs in the order the ops of a lowered
+    program's ``as_text(debug_info=True)`` first carry them."""
+    locs = dict(re.findall(r'^#(loc\d*) = loc\("([^"]*)"', text, re.M))
+    seen = []
+    for ref in re.findall(r' loc\(#(loc\d*)\)$', text, re.M):
+        parts = locs.get(ref, "").split("/")
+        period = next((x for x in parts if re.fullmatch(r"p\d+", x)), None)
+        sub = next((x for x in parts
+                    if re.fullmatch(r"s\d+\.[a-z]+", x)), None)
+        if sub is not None and (period, sub) not in seen:
+            seen.append((period, sub))
+    return seen
+
+
+@pytest.mark.parametrize("unroll", [False, True])
+@pytest.mark.parametrize("name", ARCH_NAMES)
+def test_serving_programs_carry_the_plan_scopes(name, unroll):
+    """The solo serve step, the one-token gateway step and the C=4
+    prefill step scope every sub-layer the period plan holds, in plan
+    order, in every period (one scan body when not unrolled); the MoE
+    call is unscoped."""
+    cfg = dataclasses.replace(smoke_config(name), unroll=unroll)
+    plan, n_periods = period_plan(cfg)
+    b, s = 2, 16
+    params = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(0), cfg))
+    views = jax.eval_shape(lambda: init_decode_cache(cfg, b, s))
+    tok = jax.ShapeDtypeStruct((b, 1), jnp.int32)
+    lens = jax.ShapeDtypeStruct((b,), jnp.int32)
+    solo = {"token": tok, "cache_len": jax.ShapeDtypeStruct((), jnp.int32)}
+    if cfg.family == "vlm":
+        solo["img"] = jax.ShapeDtypeStruct((b, cfg.n_img_tokens,
+                                            cfg.d_model), jnp.float32)
+    if cfg.family == "encdec":
+        solo["enc_out"] = jax.ShapeDtypeStruct((b, s, cfg.d_model),
+                                               jnp.float32)
+    programs = {"serve_step": (build_serve_step, solo)}
+    if cfg.family not in ("vlm", "encdec") and not cfg.n_experts:
+        programs["gateway_step"] = (build_gateway_step,
+                                    {"token": tok, "lens": lens})
+        if all(sub.kind == "attn" for sub in plan):
+            programs["prefill_step"] = (build_gateway_prefill_step, {
+                "token": jax.ShapeDtypeStruct((b, 4), jnp.int32),
+                "lens": lens, "n_valid": lens})
+    per_period = [f"s{i}.{mod}" for i, sub in enumerate(plan)
+                  for mod in (sub.kind, "cross" if sub.cross else None,
+                              sub.ffn if sub.ffn == "mlp" else None)
+                  if mod is not None]
+    want = [(f"p{pi}" if unroll else None, scope)
+            for pi in range(n_periods if unroll else 1)
+            for scope in per_period]
+    for prog, (build, batch) in programs.items():
+        text = jax.jit(build(cfg)).lower(params, views, batch).as_text(
+            debug_info=True)
+        assert _scope_sequence(text) == want, prog
